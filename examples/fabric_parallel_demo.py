@@ -25,7 +25,7 @@ if os.environ.get("XLA_FLAGS", "").find("device_count=8") < 0:
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.utils import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_reduced, replace

@@ -36,7 +36,7 @@ from repro.core.ca import CertificateAuthority, enroll
 from repro.core.domains import (AccessViolation, DomainKey, KeyRegistry,
                                 ProtectionDomain, RW, mac_seed)
 from repro.kernels.ref import mac_ref
-from repro.utils import axis_size, match_vma
+from repro.utils import match_vma
 
 LANES = 128
 
@@ -141,7 +141,7 @@ def neighbor_exchange(fabric: MPKLinkFabric, chan: FabricChannel, key: DomainKey
     """Ring shift over chan.axis with capability check + optional MAC guard.
     Returns (received, ok_flag)."""
     fabric.check(chan, key)
-    n = axis_size(chan.axis)
+    n = jax.lax.axis_size(chan.axis)
     perm = _perm(n, shift)
     if not chan.guard:
         return jax.lax.ppermute(x, chan.axis, perm), jnp.int32(1)
@@ -156,7 +156,7 @@ def ring_all_gather(fabric: MPKLinkFabric, chan: FabricChannel, key: DomainKey,
     """All-gather built from n-1 chained neighbor pushes (bandwidth-optimal
     ring; each step is an MPKLink channel hop). Returns (gathered, ok)."""
     fabric.check(chan, key)
-    n = axis_size(chan.axis)
+    n = jax.lax.axis_size(chan.axis)
     idx = jax.lax.axis_index(chan.axis) if axis_index is None else axis_index
 
     def body(carry, _):
@@ -179,7 +179,7 @@ def reduce_scatter_ring(fabric: MPKLinkFabric, chan: FabricChannel, key: DomainK
     n-1 hops, each hop sends one shard — the collective the §Perf pass uses
     to replace all-reduce where only shards are needed. Returns (shard, ok)."""
     fabric.check(chan, key)
-    n = axis_size(chan.axis)
+    n = jax.lax.axis_size(chan.axis)
     idx = jax.lax.axis_index(chan.axis)
     shards = x.reshape((n, x.shape[0] // n) + x.shape[1:])
 

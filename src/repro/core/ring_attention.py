@@ -28,7 +28,7 @@ from repro.core.fabric import FabricChannel, MPKLinkFabric, neighbor_exchange
 from repro.core.domains import DomainKey
 from repro.kernels.flash_jnp import _fwd_core, _pad_to
 from repro.kernels.ref import NEG_INF
-from repro.utils import axis_size, match_vma
+from repro.utils import match_vma
 
 
 def _merge(out1, lse1, out2, lse2):
@@ -53,7 +53,7 @@ def ring_attention(fabric: MPKLinkFabric, chan: FabricChannel, key: DomainKey,
     hold ABSOLUTE positions (so causal/window masks stay exact across
     blocks). → (out (B, Sq_loc, H, Dh), ok flag)."""
     fabric.check(chan, key)
-    n = axis_size(chan.axis)
+    n = jax.lax.axis_size(chan.axis)
     B, Sq, H, Dh = q.shape
 
     qc = min(q_chunk, Sq)

@@ -19,7 +19,8 @@ so authenticated transport costs ≈ a plain copy (benchmarks/kernel_bench.py
 measures exactly this delta — the paper's Table-X "security for free" claim).
 
 Grid is 1-D over row tiles, sequential; the MAC state is VMEM scratch.
-Validated in interpret mode against ref.mac_ref / ref.guard_copy_ref.
+Validated in interpret mode against ref.mac_ref / ref.guard_copy_ref, and
+compiled for the chip by tests/test_tpu_compile.py.
 
 Batch variant (the pipelined data plane): :func:`mac_batch_pallas` MACs a
 whole (N, rows, 128) stack of frames in one launch — grid (N, row-tiles),
@@ -48,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import MAC_PRIME, MAC_INIT
+from repro.utils import pallas_interpret
 
 LANES = 128
 
@@ -64,6 +66,13 @@ def _fold_powers() -> np.ndarray:
 
 
 FOLD_POWERS = _fold_powers()
+
+
+def _fold_i32(acc, powers):
+    """Σ acc_i·P^(127-i) mod 2^32 as an int32 scalar. Mosaic reduces signed
+    integers only; wrapping int32 addition is the same sum mod 2^32, so the
+    bits equal the uint32 fold of ``mac_finalize``."""
+    return jnp.sum(jax.lax.bitcast_convert_type(acc * powers, jnp.int32))
 
 
 def _guard_kernel(tag_ref, expect_ref, powers_ref, in_ref, out_ref, mac_ref,
@@ -85,44 +94,48 @@ def _guard_kernel(tag_ref, expect_ref, powers_ref, in_ref, out_ref, mac_ref,
 
     @pl.when(i == n - 1)
     def _final():
-        mac = jnp.sum(h[0, :] * powers_ref[...], dtype=jnp.uint32)
+        mac = _fold_i32(h[0, :], powers_ref[...])
         mac_ref[0] = mac
-        ok_ref[0] = (mac == expect_ref[0].astype(jnp.uint32)).astype(jnp.int32)
+        ok_ref[0] = (mac == expect_ref[0]).astype(jnp.int32)
 
 
-def guard_copy_pallas(payload_u32, tag, expected_mac, *, rows_per_tile=256,
-                      interpret=True):
+def _as_i32(x):
+    """A uint32 word → its (1,) int32 bit pattern, for an SMEM operand."""
+    return jax.lax.bitcast_convert_type(
+        jnp.asarray(x).astype(jnp.uint32).reshape(-1), jnp.int32)
+
+
+def guard_copy_pallas(payload_u32, tag, expected_mac, *, rows_per_tile=256):
     """payload (n, 128) uint32 with n % rows_per_tile == 0 (ops.py pads).
-    Returns (copy, mac (1,) uint32, ok (1,) int32)."""
+    Returns (copy, mac (1,) uint32, ok (1,) int32). The scalars ride in
+    SMEM as int32 bit patterns."""
     n, lanes = payload_u32.shape
     assert lanes == LANES and payload_u32.dtype == jnp.uint32
     rt = min(rows_per_tile, n)
     assert n % rt == 0, (n, rt)
     grid = (n // rt,)
     kernel = functools.partial(_guard_kernel, rows_per_tile=rt)
-    return pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out, mac, ok = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),         # tag
-            pl.BlockSpec((1,), lambda i: (0,)),         # expected mac
+            smem,                                       # tag
+            smem,                                       # expected mac
             pl.BlockSpec((LANES,), lambda i: (0,)),     # fold powers
             pl.BlockSpec((rt, LANES), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((rt, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
+        out_specs=[pl.BlockSpec((rt, LANES), lambda i: (i, 0)), smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((n, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((1,), jnp.uint32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        interpret=interpret,
-    )(tag.reshape(1).astype(jnp.uint32), expected_mac.reshape(1).astype(jnp.uint32),
-      jnp.asarray(FOLD_POWERS), payload_u32)
+        interpret=pallas_interpret(),
+    )(_as_i32(tag), _as_i32(expected_mac), jnp.asarray(FOLD_POWERS),
+      payload_u32)
+    return out, jax.lax.bitcast_convert_type(mac, jnp.uint32), ok
 
 
 # ---------------------------------------------------------------------------
@@ -131,6 +144,7 @@ def guard_copy_pallas(payload_u32, tag, expected_mac, *, rows_per_tile=256,
 
 def _batch_mac_kernel(tag_ref, powers_ref, in_ref, mac_ref, h,
                       *, rows_per_tile):
+    i = pl.program_id(0)
     j = pl.program_id(1)
     nt = pl.num_programs(1)
 
@@ -147,17 +161,18 @@ def _batch_mac_kernel(tag_ref, powers_ref, in_ref, mac_ref, h,
 
     @pl.when(j == nt - 1)
     def _final():
-        mac_ref[0] = jnp.sum(h[0, :] * powers_ref[...], dtype=jnp.uint32)
+        mac_ref[i] = _fold_i32(h[0, :], powers_ref[...])
 
 
-def mac_batch_pallas(stack_u32, tag, *, rows_per_tile=256, interpret=True):
+def mac_batch_pallas(stack_u32, tag, *, rows_per_tile=256):
     """(N, rows, 128) uint32 stack → (N,) uint32 MACs, one kernel launch.
 
     Grid is (frame, row-tile); the row-tile axis is innermost so each
     frame's Horner state lives in VMEM scratch across its tiles exactly like
     the scalar kernel — the batch axis just replays that schedule N times
-    without N dispatches. ``rows`` must divide by ``rows_per_tile`` (snapped
-    down here, never padded: padding rows would change the Horner MAC)."""
+    without N dispatches. The N MAC words collect in one SMEM output.
+    ``rows`` must divide by ``rows_per_tile`` (snapped down here, never
+    padded: padding rows would change the Horner MAC)."""
     n, rows, lanes = stack_u32.shape
     assert lanes == LANES and stack_u32.dtype == jnp.uint32
     rt = min(rows_per_tile, max(1, rows))
@@ -165,20 +180,20 @@ def mac_batch_pallas(stack_u32, tag, *, rows_per_tile=256, interpret=True):
         rt -= 1
     grid = (n, rows // rt)
     kernel = functools.partial(_batch_mac_kernel, rows_per_tile=rt)
-    return pl.pallas_call(
+    macs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),          # tag
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # tag
             pl.BlockSpec((LANES,), lambda i, j: (0,)),      # fold powers
             pl.BlockSpec((1, rt, LANES), lambda i, j: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.uint32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        interpret=interpret,
-    )(tag.reshape(1).astype(jnp.uint32), jnp.asarray(FOLD_POWERS),
-      stack_u32)
+        interpret=pallas_interpret(),
+    )(_as_i32(tag), jnp.asarray(FOLD_POWERS), stack_u32)
+    return jax.lax.bitcast_convert_type(macs, jnp.uint32)
 
 
 def mac_batch_jnp(stack_u32, tag):
@@ -238,7 +253,7 @@ def _mac_update_kernel(h_ref, in_ref, out_ref, acc, *, rows_per_tile):
         out_ref[...] = acc[0, :]
 
 
-def mac_update_pallas(h, block_u32, *, rows_per_tile=256, interpret=True):
+def mac_update_pallas(h, block_u32, *, rows_per_tile=256):
     """Advance a (LANES,) uint32 Horner state over an (m, 128) uint32
     block in one launch. The state rides in VMEM scratch across row tiles
     exactly like the one-shot kernels — this is the same schedule with the
@@ -264,7 +279,7 @@ def mac_update_pallas(h, block_u32, *, rows_per_tile=256, interpret=True):
         out_specs=pl.BlockSpec((LANES,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((LANES,), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(h.astype(jnp.uint32), block_u32)
 
 

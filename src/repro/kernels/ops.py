@@ -4,7 +4,8 @@ impl choices:
   attention: "naive" (oracle, O(S²) memory — smoke/small only)
              "chunked" (flash_jnp custom_vjp twin — differentiable, what the
                         dry-run lowers; the default for train/prefill)
-             "pallas"  (TPU kernel; interpret=True on CPU; fwd-only)
+             "pallas"  (TPU kernel, fwd-only; interpreted on the CPU backend,
+                        compiled on a TPU — repro.utils.pallas_interpret)
   ssd:       "ref" | "chunked" | "pallas"
   guard:     "ref" | "pallas"
 
@@ -27,7 +28,7 @@ mac = _ref.mac_ref
 
 
 def attention(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
-              impl="chunked", q_chunk=128, kv_chunk=128, interpret=True):
+              impl="chunked", q_chunk=128, kv_chunk=128):
     if impl == "naive":
         return _ref.attention_ref(q, k, v, q_pos, kv_pos, causal=causal, window=window)
     if impl == "chunked":
@@ -42,7 +43,7 @@ def attention(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
         out = flash_attention_pallas(
             _fj._pad_to(q, 1, qc, 0), _fj._pad_to(k, 1, kc, 0),
             _fj._pad_to(v, 1, kc, 0), qp, kp, causal=causal, window=window,
-            q_chunk=qc, kv_chunk=kc, interpret=interpret)
+            q_chunk=qc, kv_chunk=kc)
         return out[:, :Sq]
     if impl == "pallas_decode":
         assert q.shape[1] == 1, "pallas_decode is the single-token path"
@@ -50,13 +51,11 @@ def attention(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
         kp = _fj._pad_to(kv_pos.astype(jnp.int32), 1, kc, -1)
         return decode_attention_pallas(
             q, _fj._pad_to(k, 1, kc, 0), _fj._pad_to(v, 1, kc, 0),
-            q_pos, kp, causal=causal, window=window, kv_chunk=kc,
-            interpret=interpret)
+            q_pos, kp, causal=causal, window=window, kv_chunk=kc)
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
-def ssd(x, dt, A_log, B, C, D, init_state=None, *, chunk=128, impl="chunked",
-        interpret=True):
+def ssd(x, dt, A_log, B, C, D, init_state=None, *, chunk=128, impl="chunked"):
     if impl == "ref":
         return _ref.ssd_ref(x, dt, A_log, B, C, D, init_state)
     if impl == "chunked":
@@ -69,7 +68,7 @@ def ssd(x, dt, A_log, B, C, D, init_state=None, *, chunk=128, impl="chunked",
         Bp = _sj._pad_seq(B, Q)
         Cp = _sj._pad_seq(C, Q)
         y, sf = ssd_scan_pallas(xp, dtp, A_log, Bp, Cp, D, init_state,
-                                chunk=Q, interpret=interpret)
+                                chunk=Q)
         return y[:, :S], sf
     raise ValueError(f"unknown ssd impl {impl!r}")
 
@@ -79,7 +78,7 @@ def ssd_decode_step(x_t, dt_t, A_log, B_t, C_t, D, state):
 
 
 def guard_copy(payload_u32, tag, expected_mac, *, rows_per_tile=256,
-               impl="pallas", interpret=True):
+               impl="pallas"):
     """(copy, mac, ok). The tile size is snapped down to the largest divisor
     of the row count ≤ rows_per_tile, so the kernel never pads (padding
     would change the Horner MAC). Frames are LANES-padded by core.framing,
@@ -92,11 +91,10 @@ def guard_copy(payload_u32, tag, expected_mac, *, rows_per_tile=256,
     while n % rt:
         rt -= 1
     return guard_copy_pallas(payload_u32, tag, expected_mac,
-                             rows_per_tile=rt, interpret=interpret)
+                             rows_per_tile=rt)
 
 
-def guard_mac_batch(stack_u32, tag, *, rows_per_tile=256, impl="pallas",
-                    interpret=True):
+def guard_mac_batch(stack_u32, tag, *, rows_per_tile=256, impl="pallas"):
     """(N, rows, 128) uint32 stack of frame payloads → (N,) uint32 MACs.
 
     The device side of the batched data plane: N frames MAC'd in one fused
@@ -109,8 +107,7 @@ def guard_mac_batch(stack_u32, tag, *, rows_per_tile=256, impl="pallas",
     if impl == "jnp" or stack_u32.shape[1] == 0:
         return mac_batch_jnp(stack_u32, tag)
     if impl == "pallas":
-        return mac_batch_pallas(stack_u32, tag, rows_per_tile=rows_per_tile,
-                                interpret=interpret)
+        return mac_batch_pallas(stack_u32, tag, rows_per_tile=rows_per_tile)
     raise ValueError(f"unknown guard_mac_batch impl {impl!r}")
 
 
@@ -120,8 +117,7 @@ def guard_mac_init(tag):
     return mac_init_state(tag)
 
 
-def guard_mac_update(h, block_u32, *, rows_per_tile=256, impl="pallas",
-                     interpret=True):
+def guard_mac_update(h, block_u32, *, rows_per_tile=256, impl="pallas"):
     """Advance a streaming-MAC state over one (m, 128) uint32 block.
 
     The device side of the zero-copy seal path: a payload too large to
@@ -133,8 +129,7 @@ def guard_mac_update(h, block_u32, *, rows_per_tile=256, impl="pallas",
     if impl == "jnp" or block_u32.shape[0] == 0:
         return mac_update_jnp(h, block_u32)
     if impl == "pallas":
-        return mac_update_pallas(h, block_u32, rows_per_tile=rows_per_tile,
-                                 interpret=interpret)
+        return mac_update_pallas(h, block_u32, rows_per_tile=rows_per_tile)
     raise ValueError(f"unknown guard_mac_update impl {impl!r}")
 
 

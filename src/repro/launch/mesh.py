@@ -6,6 +6,14 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings and
+    indexing a sharded array needs no out-sharding, which is what every
+    caller here assumes (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     with the leading ``pod`` axis as the cross-DCI data-parallel dimension."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def dp_axes(multi_pod: bool):
@@ -23,4 +31,4 @@ def dp_axes(multi_pod: bool):
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for CPU tests (8 forced host devices)."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

@@ -13,6 +13,7 @@ from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.models import init_params
 from repro.models.transformer import Impl
 from repro.runtime import Request, ServingEngine
+from repro.utils import enable_compile_cache
 
 
 def main():
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--greedy", action="store_true", default=True)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     if cfg.swa_window is not None and args.max_seq > cfg.swa_window:

@@ -20,6 +20,7 @@ from repro.configs import (ARCH_IDS, OptimizerConfig, TrainConfig, get_config,
                            get_reduced)
 from repro.models.transformer import Impl
 from repro.runtime import FailureInjector, Trainer
+from repro.utils import enable_compile_cache
 
 
 def main():
@@ -37,6 +38,7 @@ def main():
                     help="full production config (TPU pods; CPU smoke uses "
                          "the reduced twin)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     print(f"arch={cfg.name} params≈{cfg.param_count()/1e6:.1f}M "

@@ -29,7 +29,6 @@ from repro.configs.base import ModelConfig
 from repro.core.domains import DomainKey
 from repro.core.fabric import FabricChannel, MPKLinkFabric, all_to_all
 from repro.models.layers import activation
-from repro.utils import axis_size
 from repro.models.moe import _route
 
 
@@ -42,7 +41,7 @@ def apply_moe_ep(cfg: ModelConfig, local_weights, x_local, *,
     "down" (le,F,D)} — expert dims pre-split by shard_map in_specs.
     x_local (B_loc, S, D) → (out (B_loc, S, D), aux)."""
     fabric.check(chan, key)
-    ep = axis_size(chan.axis)
+    ep = jax.lax.axis_size(chan.axis)
     m = cfg.moe
     E = m.num_experts
     assert E % ep == 0, (E, ep)

@@ -22,8 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils import axis_size, match_vma
-
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     xf = x.astype(jnp.float32)
@@ -50,7 +48,7 @@ def compressed_reduce(g: jnp.ndarray, ef: jnp.ndarray, axis: str):
     """All-reduce-mean of one tensor over ``axis`` with an int8 all-gather leg.
     Call inside shard_map. Falls back to exact psum when the leading dim
     doesn't tile. → (reduced (same shape as g), new_ef)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if g.ndim == 0 or g.shape[0] % n != 0:
         return jax.lax.pmean(g, axis), ef
 

@@ -17,6 +17,7 @@ import jax
 from jax.sharding import NamedSharding
 
 from repro.checkpoint import Checkpointer
+from repro.launch.mesh import auto_mesh
 
 
 def plan_remesh(n_alive_chips: int, tp: int = 16,
@@ -35,7 +36,7 @@ def remesh(n_alive_chips: int, tp: int = 16, axes=("data", "model")):
         raise RuntimeError(
             f"not enough chips ({n_alive_chips}) for one tp={tp} row")
     shape, names = plan
-    return jax.make_mesh(shape, names)
+    return auto_mesh(shape, names)
 
 
 def elastic_restore(ckpt: Checkpointer, like_tree, mesh, spec_tree,

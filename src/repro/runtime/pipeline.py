@@ -28,7 +28,7 @@ from repro.configs.base import ModelConfig
 from repro.core.domains import DomainKey
 from repro.core.fabric import FabricChannel, MPKLinkFabric, neighbor_exchange
 from repro.models.transformer import Impl, apply_block
-from repro.utils import axis_size, match_vma
+from repro.utils import match_vma
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2))
@@ -67,7 +67,7 @@ def pipeline_apply(cfg: ModelConfig, local_params, x_micro, *,
     final broadcast from the last stage, ok flag)."""
     fabric.check(chan, key)
     assert not cfg.moe, "pipeline stages compose with moe_ep, not dense MoE"
-    n = axis_size(chan.axis)
+    n = jax.lax.axis_size(chan.axis)
     sid = jax.lax.axis_index(chan.axis)
     params = jax.tree.map(lambda a: a[0], local_params)      # (L/n, ...)
     n_micro, mb, S, D = x_micro.shape

@@ -17,7 +17,7 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,19 +49,35 @@ class Request:
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_seq: int = 256, impl: Impl = Impl(remat=False),
-                 dtype=jnp.float32, greedy: bool = True, seed: int = 0):
+                 dtype=jnp.float32, greedy: bool = True, seed: int = 0,
+                 device: Optional[jax.Device] = None):
+        """``device`` is where the params, caches and every decode step
+        live; None places them on the default backend's first device. A
+        device of another platform than the backend raises: the engine
+        would otherwise serve from a device nobody meant to use."""
         assert cfg.swa_window is None or max_seq <= cfg.swa_window, \
             "ring caches need uniform positions; lower max_seq or use dense"
-        self.cfg, self.params = cfg, params
+        if device is None:
+            device = jax.devices()[0]
+        elif device.platform != jax.default_backend():
+            raise ValueError(
+                f"ServingEngine device {device} is a {device.platform!r} "
+                f"device but the backend is {jax.default_backend()!r}")
+        self.device = device
+        self.cfg, self.params = cfg, jax.device_put(params, device)
         self.B, self.max_seq = max_batch, max_seq
         self.impl, self.dtype = impl, dtype
         self.greedy = greedy
-        self.key = jax.random.PRNGKey(seed)
+        self.key = jax.device_put(jax.random.PRNGKey(seed), device)
 
-        state = init_decode_state(cfg, params, max_batch, max_seq,
-                                  dtype=dtype, impl=impl)
-        state["pos"] = jnp.zeros((max_batch,), jnp.int32)
-        self.state = state
+        # made on the device, then committed to it: an uncommitted cache
+        # would let the first admission's un-jitted row reset run on the
+        # default device (a copy of the whole cache on device 0 per replica)
+        with jax.default_device(device):
+            state = init_decode_state(cfg, self.params, max_batch, max_seq,
+                                      dtype=dtype, impl=impl)
+            state["pos"] = jnp.zeros((max_batch,), jnp.int32)
+        self.state = jax.device_put(state, device)
         self._step = jax.jit(
             lambda p, s, t: decode_step(cfg, p, s, t, impl=impl, dtype=dtype))
 
@@ -111,8 +127,9 @@ class ServingEngine:
         self._admit()
         if all(s is None for s in self.slots):
             return False
-        logits, self.state = self._step(self.params, self.state,
-                                        jnp.asarray(self.current_token))
+        logits, self.state = self._step(
+            self.params, self.state,
+            jax.device_put(self.current_token, self.device))
         if self.greedy:
             nxt = np.asarray(jnp.argmax(logits[:, -1], -1), np.int32)
         else:
@@ -153,7 +170,8 @@ class ServingEngine:
         self.queue = []
         self.current_token[:] = 0
         self.prompt_cursor[:] = 0
-        self.state["pos"] = jnp.zeros((self.B,), jnp.int32)
+        self.state["pos"] = jax.device_put(
+            np.zeros((self.B,), np.int32), self.device)
         return lost
 
 
@@ -164,6 +182,22 @@ class ServingEngine:
 def encode_prompt(prompt: List[int], max_new: int = 16) -> np.ndarray:
     """Gateway wire format for EngineService: int32 [max_new, *prompt]."""
     return np.asarray([max_new, *prompt], np.int32)
+
+
+def decode_tokens(arr) -> np.ndarray:
+    """A wire payload of int32 words → flat int32 array: an EngineService
+    request at its handler, or its answer at the client. Transport hops
+    are byte-oriented, so either can arrive as raw bytes (a fleet-routed
+    answer always does, often as a read-only view of a region/arena
+    slot); a contiguous payload is reinterpreted in place."""
+    arr = np.asarray(arr)
+    if arr.dtype != np.int32:
+        if arr.flags.c_contiguous and arr.nbytes % 4 == 0:
+            arr = arr.reshape(-1).view(np.uint8).view(np.int32)
+        else:
+            arr = np.frombuffer(np.ascontiguousarray(arr).tobytes(),
+                                np.int32)
+    return arr.reshape(-1)
 
 
 class EngineService:
@@ -303,14 +337,7 @@ class EngineService:
         transport region/arena slot; a contiguous whole-word payload is
         reinterpreted in place (no tobytes() snapshot — the prompt ints are
         consumed before the handler returns, within the view's lifetime)."""
-        arr = np.asarray(req)
-        if arr.dtype != np.int32:
-            if arr.flags.c_contiguous and arr.nbytes % 4 == 0:
-                arr = arr.reshape(-1).view(np.uint8).view(np.int32)
-            else:
-                arr = np.frombuffer(np.ascontiguousarray(arr).tobytes(),
-                                    np.int32)
-        arr = arr.reshape(-1)
+        arr = decode_tokens(req)
         if arr.size < 2:
             raise ValueError("inference request needs [max_new, tok0, ...]")
         return int(arr[0]), [int(t) for t in arr[1:]]
@@ -440,44 +467,20 @@ class EngineService:
 # replica fleets (N engines behind one service name)
 # ---------------------------------------------------------------------------
 
-def fleet_handler(engine_factory: Callable[[], ServingEngine], *,
-                  timeout: float = 300.0):
-    """Service handler for one proc-backed engine replica.
-
-    The EngineService — engine, slot grid, AND its tick-loop thread — is
-    constructed lazily inside the replica's forked child on first request:
-    threads do not survive ``fork``, so an EngineService started in the
-    gateway process would reach the child as a dead tick loop and every
-    submit would stall out its deadline. Lazy construction also keeps
-    replica registration cheap (the fork itself is already lazy in
-    procwire) and gives each replica a fully private engine.
-    """
-    state: Dict[str, EngineService] = {}
-
-    def handler(req: np.ndarray) -> np.ndarray:
-        svc = state.get("svc")
-        if svc is None:
-            svc = state["svc"] = EngineService(
-                engine_factory(), timeout=timeout).start()
-        return svc.handler(req)
-
-    return handler
-
-
-def register_engine_fleet(gw, name: str,
-                          engine_factory: Callable[[], ServingEngine],
-                          replicas: int, *,
-                          transport: str = "mpklink_opt_proc",
-                          transport_kwargs: Optional[dict] = None,
-                          timeout: float = 300.0) -> List[int]:
-    """Register ``replicas`` independent engine replicas behind one service
-    name on ``gw`` (a :class:`repro.core.gateway.ServiceGateway`). Each
-    replica is its own transport instance — own protection domain, epoch,
-    shm segment, and (for proc transports) its own child process running a
-    private engine via :func:`fleet_handler`. → the replica ids, in join
-    order."""
-    return [gw.register_replica(name, fleet_handler(engine_factory,
-                                                    timeout=timeout),
-                                transport=transport,
-                                transport_kwargs=transport_kwargs)
-            for _ in range(replicas)]
+def register_engine_fleet(gw, name: str, engines: List[ServingEngine], *,
+                          timeout: float = 300.0) -> Dict[int, EngineService]:
+    """Register one in-process replica per engine behind one service name
+    on ``gw`` (a :class:`repro.core.gateway.ServiceGateway`). Build the
+    engines one per device (``ServingEngine(..., device=d)``): a chip
+    belongs to one process, so engine replicas are never forked — each is
+    an :class:`EngineService` tick loop in this process behind its own
+    ``mpklink_opt`` transport instance (own protection domain and epoch),
+    routed by the fleet's :class:`repro.core.gateway.ReplicaRouter`.
+    Forked ``procwire`` replicas remain for handlers that do not touch
+    JAX. → {replica id: its started EngineService}, in join order."""
+    fleet = {}
+    for engine in engines:
+        svc = EngineService(engine, timeout=timeout).start()
+        fleet[gw.register_replica(name, svc.handler,
+                                  transport="mpklink_opt")] = svc
+    return fleet

@@ -213,8 +213,9 @@ def test_checkpoint_codec_fallback_roundtrip():
 
 
 def test_shard_map_importable_on_this_jax():
-    from repro.utils import axis_size, shard_map
-    assert callable(shard_map) and callable(axis_size)
+    import jax
+    from jax import shard_map
+    assert callable(shard_map) and callable(jax.lax.axis_size)
 
 
 def test_shm_oversized_response_raises_not_hangs():

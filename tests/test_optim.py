@@ -77,10 +77,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.utils import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_test_mesh
 from repro.optim import compressed_reduce
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_test_mesh((8,), ("pod",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 16, 4))
 
 def f(gl, ef):

@@ -11,7 +11,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.utils import shard_map
+from jax import shard_map
 from repro.core.fabric import MPKLinkFabric
 from repro.core.ring_attention import ring_attention
 from repro.kernels.ref import attention_ref

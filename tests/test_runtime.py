@@ -1,5 +1,6 @@
 """Runtime: trainer restart semantics, stragglers, serving engine, elastic."""
 import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +129,107 @@ def test_serving_determinism_vs_decode():
         if t >= len(prompt) - 1:
             out.append(nxt)
     assert done[0].generated == out
+
+
+def test_serving_engine_places_on_its_device():
+    """No device → the backend's first device, explicitly; params, caches
+    and every step's outputs live there."""
+    cfg = get_reduced("olmo-1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=16, impl=IMPL)
+    assert eng.device == jax.devices()[0]
+    # committed from the start, so the first admission's row reset cannot
+    # drift to the default device
+    assert all(leaf.committed for leaf in jax.tree.leaves(eng.state))
+    eng.submit(Request(rid=0, prompt=[3, 4], max_new=2))
+    eng.run_until_drained()
+    placed = {d for leaf in jax.tree.leaves((eng.params, eng.state))
+              for d in leaf.devices()}
+    assert placed == {eng.device}
+    dev = jax.devices()[-1]
+    assert ServingEngine(cfg, params, max_batch=2, max_seq=16, impl=IMPL,
+                         device=dev).device == dev
+
+
+def test_serving_engine_rejects_device_of_another_platform():
+    class ForeignDevice:
+        platform = "tpu" if jax.default_backend() != "tpu" else "cpu"
+
+    cfg = get_reduced("olmo-1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="backend"):
+        ServingEngine(cfg, params, max_batch=2, max_seq=16, impl=IMPL,
+                      device=ForeignDevice())
+
+
+def test_engine_fleet_replicas_run_in_process():
+    """register_engine_fleet puts one EngineService per engine behind an
+    in-process mpklink_opt replica transport: nothing is forked (a chip
+    belongs to one process), answers decode to max_new int32 tokens."""
+    import multiprocessing
+    import threading
+
+    from repro.core import ServiceGateway
+    from repro.core.transports import MPKLinkOptTransport
+    from repro.runtime.serve import (decode_tokens, encode_prompt,
+                                     register_engine_fleet)
+
+    cfg = get_reduced("olmo-1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    engines = [ServingEngine(cfg, params, max_batch=2, max_seq=32, impl=IMPL)
+               for _ in range(2)]
+    gw = ServiceGateway("mpklink_opt")
+    fleet = register_engine_fleet(gw, "llm", engines, timeout=60.0)
+    try:
+        assert sorted(fleet) == [0, 1]
+        reps = gw.fleet("llm")._replicas
+        assert all(isinstance(reps[r].transport, MPKLinkOptTransport)
+                   for r in fleet)
+        outs = [None] * 6
+
+        def call(i):
+            cli = gw.connect(f"fleet-client-{i}")
+            outs[i] = decode_tokens(cli.call(
+                "llm", encode_prompt([1 + i, 2, 3], max_new=4)))
+
+        threads = [threading.Thread(target=call, args=(i,)) for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert all(o is not None and o.dtype == np.int32 and o.shape == (4,)
+                   for o in outs), outs
+        assert sum(reps[r].served for r in fleet) == 6
+        assert not multiprocessing.active_children()
+        assert all(svc.crashes == 0 for svc in fleet.values())
+    finally:
+        gw.close()
+        for svc in fleet.values():
+            svc.close()
+
+
+def test_pallas_interpret_follows_backend(monkeypatch):
+    from repro.utils import pallas_interpret
+    assert pallas_interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        pallas_interpret()
+
+
+def test_compile_cache_dir_from_env_or_fixed_repo_path(monkeypatch):
+    from repro import utils
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert utils.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = utils.enable_compile_cache()
+        assert got == str(utils.REPO_CACHE_DIR) \
+            == jax.config.jax_compilation_cache_dir
+        assert utils.REPO_CACHE_DIR.name == ".jax_cache"
+        assert utils.REPO_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
