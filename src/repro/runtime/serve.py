@@ -27,6 +27,7 @@ from repro.configs.base import ModelConfig
 from repro.core.transports import ServiceCrashed
 from repro.models import decode_step, init_decode_state
 from repro.models.transformer import Impl
+from repro.runtime import telemetry
 
 
 @dataclass
@@ -42,7 +43,10 @@ class Request:
     generated: List[int] = field(default_factory=list)
     slot: int = -1
     done: bool = False
+    # perf_counter stamps: queued, given a slot, first token, retired
     submitted_at: float = 0.0
+    admitted_at: float = 0.0
+    first_token_at: float = 0.0
     finished_at: float = 0.0
 
 
@@ -78,15 +82,29 @@ class ServingEngine:
                                       dtype=dtype, impl=impl)
             state["pos"] = jnp.zeros((max_batch,), jnp.int32)
         self.state = jax.device_put(state, device)
-        self._step = jax.jit(
-            lambda p, s, t: decode_step(cfg, p, s, t, impl=impl, dtype=dtype))
+
+        def engine_decode_step(p, s, t):    # its module: jit_engine_decode_step
+            return decode_step(cfg, p, s, t, impl=impl, dtype=dtype)
+        self._step = jax.jit(engine_decode_step)
 
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.queue: List[Request] = []
         self.current_token = np.zeros((max_batch, 1), np.int32)
         self.prompt_cursor = np.zeros(max_batch, np.int64)
         self.completed: List[Request] = []
+        # written by the tick alone: ticks that ran the step; occupied
+        # slots a tick that fed a prompt token, and those that generated
+        # one; blocking device-to-host reads (the sampled tokens, and each
+        # generating slot's position)
         self.ticks = 0
+        self.prompt_slot_ticks = 0
+        self.decode_slot_ticks = 0
+        self.host_syncs = 0
+        # span names, tagged with the chip whose idle time they explain
+        (self._sp_tick, self._sp_admit, self._sp_slot_reset,
+         self._sp_dispatch, self._sp_sample, self._sp_bookkeep) = (
+            f"engine.{phase}:{device.id}" for phase in
+            ("tick", "admit", "slot_reset", "dispatch", "sample", "bookkeep"))
 
     # -- request management -----------------------------------------------
     def submit(self, req: Request):
@@ -106,12 +124,14 @@ class ServingEngine:
                                        k))
                 req = self.queue.pop(i)
                 req.slot = b
+                req.admitted_at = time.perf_counter()
                 self.slots[b] = req
                 # reset slot: zero its cache rows + position
-                self.state["caches"] = jax.tree.map(
-                    lambda c: c.at[:, b].set(0) if c.ndim >= 2 else c,
-                    self.state["caches"])
-                self.state["pos"] = self.state["pos"].at[b].set(0)
+                with telemetry.span(self._sp_slot_reset):
+                    self.state["caches"] = jax.tree.map(
+                        lambda c: c.at[:, b].set(0) if c.ndim >= 2 else c,
+                        self.state["caches"])
+                    self.state["pos"] = self.state["pos"].at[b].set(0)
                 self.current_token[b, 0] = req.prompt[0]
                 self.prompt_cursor[b] = 1
 
@@ -124,19 +144,35 @@ class ServingEngine:
 
     # -- engine tick ---------------------------------------------------------
     def tick(self):
-        self._admit()
-        if all(s is None for s in self.slots):
-            return False
-        logits, self.state = self._step(
-            self.params, self.state,
-            jax.device_put(self.current_token, self.device))
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits[:, -1], -1), np.int32)
-        else:
-            self.key, k = jax.random.split(self.key)
-            nxt = np.asarray(jax.random.categorical(k, logits[:, -1]), np.int32)
-        self.ticks += 1
+        """One step of the slot grid. Its spans, in order, cover it:
+        admit (with each slot reset), dispatch, sample, bookkeep."""
+        span = telemetry.span
+        with span(self._sp_tick):
+            with span(self._sp_admit):
+                self._admit()
+                if all(s is None for s in self.slots):
+                    return False
+            with span(self._sp_dispatch):
+                logits, self.state = self._step(
+                    self.params, self.state,
+                    jax.device_put(self.current_token, self.device))
+            with span(self._sp_sample):
+                if self.greedy:
+                    nxt = np.asarray(jnp.argmax(logits[:, -1], -1), np.int32)
+                else:
+                    self.key, k = jax.random.split(self.key)
+                    nxt = np.asarray(
+                        jax.random.categorical(k, logits[:, -1]), np.int32)
+                self.host_syncs += 1
+            with span(self._sp_bookkeep):
+                self.ticks += 1
+                self._bookkeep(nxt)
+        return True
 
+    def _bookkeep(self, nxt: np.ndarray):
+        """Advance every occupied slot by the tick's sampled tokens: feed
+        the next prompt token, or take the sample and retire the request
+        when it is done."""
         for b, req in enumerate(self.slots):
             if req is None:
                 continue
@@ -144,16 +180,20 @@ class ServingEngine:
             if cur < len(req.prompt):              # still feeding the prompt
                 self.current_token[b, 0] = req.prompt[cur]
                 self.prompt_cursor[b] = cur + 1
+                self.prompt_slot_ticks += 1
                 continue
+            self.decode_slot_ticks += 1
             tok = int(nxt[b])
+            if not req.generated:
+                req.first_token_at = time.perf_counter()
             req.generated.append(tok)
             self.current_token[b, 0] = tok
             pos = int(self.state["pos"][b])
+            self.host_syncs += 1
             if (len(req.generated) >= req.max_new
                     or (req.eos_id is not None and tok == req.eos_id)
                     or pos >= self.max_seq - 1):
                 self._retire(b)
-        return True
 
     def run_until_drained(self, max_ticks: int = 10_000):
         while (self.queue or any(s is not None for s in self.slots)) \
@@ -200,6 +240,23 @@ def decode_tokens(arr) -> np.ndarray:
     return arr.reshape(-1)
 
 
+class _SpannedLock:
+    """A lock whose acquisition, and not its hold, is one named span:
+    ``with`` takes the lock inside ``span(name)`` and releases it on exit."""
+
+    __slots__ = ("_lock", "_name")
+
+    def __init__(self, lock: threading.Lock, name: str):
+        self._lock, self._name = lock, name
+
+    def __enter__(self):
+        with telemetry.span(self._name):
+            self._lock.acquire()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class EngineService:
     """Thread-safe inference service over a :class:`ServingEngine`.
 
@@ -227,6 +284,13 @@ class EngineService:
         self.timeout = timeout
         self._idle_wait = idle_wait
         self._lock = threading.Lock()           # guards engine + tables
+        # the same lock, with the wait for it a span: the tick loop's, and
+        # the handler threads'
+        dev = engine.device.id
+        self._tick_lock = _SpannedLock(self._lock,
+                                       f"service.lock_wait.tick:{dev}")
+        self._caller_lock = _SpannedLock(self._lock,
+                                         f"service.lock_wait.caller:{dev}")
         self._events: Dict[int, threading.Event] = {}
         self._done: Dict[int, Request] = {}
         self._failed: Dict[int, BaseException] = {}
@@ -237,7 +301,8 @@ class EngineService:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.crashes = 0                        # tick-loop crashes survived
-        self.cohorts: List[int] = []            # batch-submission sizes seen
+        self.cohorts_seen = 0                   # batch submissions taken
+        self.max_cohort = 0                     # the largest of them
         self._inject_crash = False              # test hook: die on next tick
 
     # -- lifecycle ---------------------------------------------------------
@@ -302,7 +367,7 @@ class EngineService:
     def _run(self):
         while not self._stop.is_set():
             try:
-                with self._lock:
+                with self._tick_lock:
                     if self._inject_crash:
                         self._inject_crash = False
                         raise RuntimeError("injected engine crash")
@@ -361,7 +426,7 @@ class EngineService:
         """Block until ``rid`` retires (bounded by ``deadline``); return its
         generated tokens or raise its typed failure."""
         ev.wait(timeout=max(0.0, deadline - time.monotonic()))
-        with self._lock:
+        with self._caller_lock:
             done = self._done.pop(rid, None)
             failed = self._failed.pop(rid, None)
         if done is not None:
@@ -371,7 +436,7 @@ class EngineService:
         if self._stop.is_set():
             raise RuntimeError(
                 f"EngineService closed while request {rid} was in flight")
-        with self._lock:
+        with self._caller_lock:
             self._cancel(rid)
         from repro.core import gateway as _gw     # no import cycle: lazy
         from repro.core.transports import DeadlineExpired
@@ -408,7 +473,7 @@ class EngineService:
         from repro.core import gateway as _gw     # no import cycle: lazy
         prio = _gw.current_priority()
         ev = threading.Event()
-        with self._lock:
+        with self._caller_lock:
             rid = next(self._rid)
             self._events[rid] = ev
             self.engine.submit(Request(rid=rid, prompt=prompt,
@@ -426,7 +491,8 @@ class EngineService:
         the explicit batch envelope AND an auto-coalesced cohort of inline
         calls (the gateway mux's scatter group) land here, so transparent
         coalescing reaches the decode grid as one admission unit
-        (``cohorts`` records each submission's size for observability).
+        (``cohorts_seen`` counts the submissions, ``max_cohort`` keeps the
+        largest).
         Returns the N generated-token arrays in request order; if any
         request fails (engine crash mid-decode, timeout) its typed error is
         raised and the rest of the cohort is cancelled — the gateway turns
@@ -437,8 +503,9 @@ class EngineService:
         from repro.core import gateway as _gw     # no import cycle: lazy
         prio = _gw.current_priority()   # the cohort's most-urgent class
         waits = []
-        with self._lock:
-            self.cohorts.append(len(parsed))
+        with self._caller_lock:
+            self.cohorts_seen += 1
+            self.max_cohort = max(self.max_cohort, len(parsed))
             for max_new, prompt in parsed:
                 rid = next(self._rid)
                 ev = threading.Event()
@@ -454,7 +521,7 @@ class EngineService:
             try:
                 outs.append(self._await(rid, ev, deadline))
             except BaseException:
-                with self._lock:        # don't strand the rest of the cohort
+                with self._caller_lock:  # don't strand the rest of the cohort
                     for later_rid, _ in waits[k + 1:]:
                         self._cancel(later_rid)
                 raise
