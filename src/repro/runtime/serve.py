@@ -94,8 +94,7 @@ class ServingEngine:
         self.completed: List[Request] = []
         # written by the tick alone: ticks that ran the step; occupied
         # slots a tick that fed a prompt token, and those that generated
-        # one; blocking device-to-host reads (the sampled tokens, and each
-        # generating slot's position)
+        # one; blocking device-to-host reads (the sampled tokens)
         self.ticks = 0
         self.prompt_slot_ticks = 0
         self.decode_slot_ticks = 0
@@ -188,12 +187,18 @@ class ServingEngine:
                 req.first_token_at = time.perf_counter()
             req.generated.append(tok)
             self.current_token[b, 0] = tok
-            pos = int(self.state["pos"][b])
-            self.host_syncs += 1
             if (len(req.generated) >= req.max_new
                     or (req.eos_id is not None and tok == req.eos_id)
-                    or pos >= self.max_seq - 1):
+                    or self.position(b) >= self.max_seq - 1):
                 self._retire(b)
+
+    def position(self, b: int) -> int:
+        """Slot ``b``'s position in ``state["pos"]``, known on the host:
+        admission zeroes it and every step adds one, so it counts the
+        tokens the slot has fed, which are all but the one waiting in
+        ``current_token`` (the prompt's next, or the newest sample)."""
+        req = self.slots[b]
+        return int(self.prompt_cursor[b]) + len(req.generated) - 1
 
     def run_until_drained(self, max_ticks: int = 10_000):
         while (self.queue or any(s is not None for s in self.slots)) \
