@@ -35,10 +35,19 @@ class SSMConfig:
 class MoEConfig:
     """Top-k routed mixture-of-experts FFN.
 
-    ``group_size``: tokens are routed in independent groups of this size
-    (GShard "groups"). None = one global group — the naive baseline whose
-    dispatch einsums are QUADRATIC in tokens (recorded as such in
-    EXPERIMENTS.md §Perf; the grouped variant is hillclimb iteration 1)."""
+    ``num_experts`` is the router's width, the published count. Each token
+    takes its ``top_k`` largest router logits and a softmax over those.
+
+    ``group_size`` (the capacity layer of the ``moe`` family): tokens are
+    routed in independent groups of this size (GShard "groups"). None =
+    one global group, whose dispatch einsums are quadratic in tokens
+    (ROADMAP 1.7).
+
+    ``held_experts`` and ``first_expert`` (the dropless layer of the
+    pattern hybrid): this chip holds experts ``[first_expert, first_expert
+    + held_experts)`` and computes their part of the result; None holds
+    all of them. ``shared_d_ff`` > 0 adds a shared SwiGLU expert of that
+    width, which every token passes through."""
 
     num_experts: int = 8
     top_k: int = 2
@@ -46,6 +55,14 @@ class MoEConfig:
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
     group_size: Optional[int] = None
+    held_experts: Optional[int] = None
+    first_expert: int = 0
+    shared_d_ff: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.held_experts is None \
+            else self.held_experts
 
 
 @dataclass(frozen=True)
@@ -74,6 +91,19 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     attn_every: int = 0               # hybrid: one (shared) attn block every N ssm blocks
     shared_attn: bool = False         # hybrid: attention weights shared across insertions
+    # pattern hybrid (granite-4.0-h): each layer's mixer, "mamba" or
+    # "attention", then the layer's feed-forward; replaces attn_every
+    layer_types: Optional[Tuple[str, ...]] = None
+    # attention without positional encoding (NoPE), and its softmax scale
+    # (None: 1/sqrt(head_dim))
+    use_rope: bool = True
+    attention_multiplier: Optional[float] = None
+    # granite scalars: x0 = embed * embedding_multiplier; the pattern
+    # hybrid's mixer and feed-forward outputs are scaled by
+    # residual_multiplier; logits are divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # encoder-decoder (audio family)
     enc_dec: bool = False
     enc_layers: int = 0
@@ -94,6 +124,22 @@ class ModelConfig:
         assert self.family in FAMILIES, self.family
         if self.num_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # a configuration read from JSON brings its groups as dicts and lists
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if isinstance(self.ssm, dict):
+            object.__setattr__(self, "ssm", SSMConfig(**self.ssm))
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            object.__setattr__(self, "layer_types", kinds)
+            if len(kinds) != self.num_layers or \
+                    not set(kinds) <= {"mamba", "attention"}:
+                raise ValueError(f"layer_types must name mamba or attention "
+                                 f"for each of {self.num_layers} layers: "
+                                 f"{kinds}")
+        elif self.residual_multiplier != 1.0:
+            raise ValueError("residual_multiplier applies to the pattern "
+                             "hybrid (layer_types) only")
 
     # -- derived ------------------------------------------------------------
     @property
@@ -122,7 +168,8 @@ class ModelConfig:
         return (self.d_inner // self.ssm.head_dim) if self.ssm else 0
 
     def param_count(self) -> int:
-        """Approximate parameter count (exact for what we instantiate)."""
+        """Parameter count, exact for what ``init_params`` instantiates
+        (the pattern hybrid counts the experts held here)."""
         c, D = self, self.d_model
         n = c.vocab_size * D                      # embed
         if not c.tie_embeddings:
@@ -134,19 +181,25 @@ class ModelConfig:
         )
         per_ffn = (3 if c.mlp_type == "glu" else 2) * D * c.d_ff  # (gate,) up, down
         if c.moe:
-            per_ffn = c.moe.num_experts * per_ffn + D * c.moe.num_experts
+            per_ffn = (c.moe.held * per_ffn + D * c.moe.num_experts
+                       + 3 * D * c.moe.shared_d_ff)
         per_ssm = 0
         if c.ssm:
             di, s = c.d_inner, c.ssm
+            conv_ch = di + 2 * s.n_groups * s.d_state
             per_ssm = (
                 D * (2 * di + 2 * s.n_groups * s.d_state + self.ssm_heads)  # in_proj(zx) + BC + dt
-                + s.conv_width * (di + 2 * s.n_groups * s.d_state)           # conv
-                + self.ssm_heads * 2                                          # A_log, D
+                + (s.conv_width + 1) * conv_ch                                # conv + bias
+                + self.ssm_heads * 3                                          # A_log, D, dt_bias
                 + di * D                                                      # out_proj
                 + di                                                          # gate norm
             )
         norm_p = 0 if c.norm_type == "np_layernorm" else D
-        if c.family == "ssm":
+        if c.layer_types:
+            n_attn = c.layer_types.count("attention")
+            n += ((c.num_layers - n_attn) * per_ssm + n_attn * per_attn
+                  + c.num_layers * (per_ffn + 2 * norm_p) + norm_p)
+        elif c.family == "ssm":
             n += c.num_layers * (per_ssm + 2 * norm_p)
         elif c.family == "hybrid":
             n_attn = 1 if c.shared_attn else max(1, c.num_layers // max(1, c.attn_every))
@@ -161,12 +214,14 @@ class ModelConfig:
         return int(n)
 
     def active_param_count(self) -> int:
-        """Activated params per token (MoE counts top_k experts only)."""
+        """Activated params per token: of the experts held, a token is
+        expected to reach top_k / num_experts of them."""
         if not self.moe:
             return self.param_count()
-        c = self
+        c, m = self, self.moe
         dense_ffn = 3 * c.d_model * c.d_ff
-        unused = (c.moe.num_experts - c.moe.top_k) * dense_ffn * c.num_layers
+        unused = (m.held - m.top_k * m.held / m.num_experts) * dense_ffn \
+            * c.num_layers
         return int(self.param_count() - unused)
 
 

@@ -17,6 +17,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "mixtral-8x7b": "repro.configs.mixtral_8x7b",
     "grok-1-314b": "repro.configs.grok1_314b",
     "whisper-tiny": "repro.configs.whisper_tiny",
+    "granite-4.0-h-small": "repro.configs.granite4h_small",
 }
 
 ARCH_IDS: Tuple[str, ...] = tuple(_ARCH_MODULES)

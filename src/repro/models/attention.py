@@ -1,4 +1,5 @@
-"""GQA attention layer: projections, RoPE, qk-norm, cache handling.
+"""GQA attention layer: projections, RoPE (or none: ``cfg.use_rope``),
+qk-norm, the softmax scale (``cfg.attention_multiplier``), cache handling.
 
 The attention math itself lives in repro.kernels.ops (naive oracle /
 chunked flash twin / Pallas kernel); this module owns parameters and the
@@ -62,6 +63,9 @@ def _project_qkv(cfg: ModelConfig, p, x, x_kv=None):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if cfg.attention_multiplier is not None:
+        # the kernels scale scores by 1/sqrt(head_dim): fold the ratio into q
+        q = q * (cfg.attention_multiplier * cfg.head_dim ** 0.5)
     return q, k, v
 
 
@@ -82,7 +86,7 @@ def apply_attn(cfg: ModelConfig, p, x, *, positions, causal=True,
                use_rope=True, impl="chunked", q_chunk=128, kv_chunk=128):
     """Full-sequence self-attention (train / prefill)."""
     q, k, v = _project_qkv(cfg, p, x)
-    if use_rope:
+    if use_rope and cfg.use_rope:
         q, k = _rope_qk(cfg, q, k, positions, positions)
     o = kops.attention(q, k, v, positions, positions, causal=causal,
                        window=cfg.swa_window, impl=impl,
@@ -105,7 +109,7 @@ def prefill_attn(cfg: ModelConfig, p, x, cache, *, positions, use_rope=True,
                  impl="chunked", q_chunk=128, kv_chunk=128):
     """Self-attention that also fills a dense cache starting at position 0."""
     q, k, v = _project_qkv(cfg, p, x)
-    if use_rope:
+    if use_rope and cfg.use_rope:
         q, k = _rope_qk(cfg, q, k, positions, positions)
     cache = kvcache.dense_cache_insert(cache, k, v, jnp.int32(0))
     o = kops.attention(q, k, v, positions, positions, causal=True,
@@ -138,7 +142,7 @@ def decode_attn(cfg: ModelConfig, p, x_new, cache, pos, *, use_rope=True,
                            kv_chunk=kv_chunk)
         return _out_proj(p, o), cache
 
-    if use_rope:
+    if use_rope and cfg.use_rope:
         q, k = _rope_qk(cfg, q, k, q_pos, q_pos)
 
     if "slot_pos" in cache:                       # SWA ring buffer

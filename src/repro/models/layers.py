@@ -143,6 +143,8 @@ def lm_logits(cfg: ModelConfig, params, x):
     # logits accumulate in f32: vocab reductions in bf16 lose ~2 bits of logit
     logits = jnp.einsum("...d,dv->...v", x, w.astype(x.dtype),
                         preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     vp = logits.shape[-1]
     if vp != cfg.vocab_size:
         pad_mask = jnp.arange(vp) >= cfg.vocab_size

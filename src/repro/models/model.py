@@ -43,7 +43,9 @@ def init_params(cfg: ModelConfig, key):
     ks = jax.random.split(key, 8)
     params = {"embed": init_embed(cfg, ks[0]), "final_norm": init_norm(cfg, ks[1])}
 
-    if cfg.family == "hybrid":
+    if cfg.layer_types:
+        params["blocks"] = tf.init_pattern_stack(cfg, ks[2])
+    elif cfg.family == "hybrid":
         def init_mamba_block(k):
             k1, k2 = jax.random.split(k)
             return {"ln1": init_norm(cfg, k1), "mamba": ssm_mod.init_mamba(cfg, k2)}
@@ -73,10 +75,17 @@ def init_params(cfg: ModelConfig, key):
 # forward (train / prefill logits)
 # ---------------------------------------------------------------------------
 
+def _embed(cfg: ModelConfig, params, tokens, dtype):
+    x = embed_tokens(params["embed"], tokens, dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
+    return x
+
+
 def _embed_input(cfg: ModelConfig, params, batch, dtype):
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = embed_tokens(params["embed"], tokens, dtype)
+    x = _embed(cfg, params, tokens, dtype)
     if cfg.vision_tokens and "vision_embeds" in batch:
         ve = batch["vision_embeds"].astype(dtype)
         vp = params["vision_proj"]
@@ -111,6 +120,9 @@ def forward(cfg: ModelConfig, params, batch, *, impl: Impl = Impl(),
         x = x + sinusoid(S, cfg.d_model).astype(dtype)[None]
         x, aux = tf.apply_dec_stack(cfg, params["blocks"], x, enc_out,
                                     positions=positions, impl=impl)
+    elif cfg.layer_types:
+        x, aux = tf.apply_pattern_stack(cfg, params["blocks"], x,
+                                        positions=positions, impl=impl)
     elif cfg.family == "hybrid":
         x, aux = tf.apply_hybrid_stack(cfg, params["blocks"], params["shared_attn"],
                                        x, positions=positions, impl=impl)
@@ -163,23 +175,42 @@ def _attn_cache_spec(cfg: ModelConfig, batch: int, max_seq: int, dtype):
                                     cfg.head_dim, dtype)
 
 
+def _ssm_state_spec(cfg: ModelConfig, batch: int, dtype):
+    s = cfg.ssm
+    return kvcache.init_ssm_state(batch, cfg.ssm_heads, s.head_dim, s.d_state,
+                                  s.conv_width,
+                                  cfg.d_inner + 2 * s.n_groups * s.d_state,
+                                  dtype)
+
+
 def init_decode_state(cfg: ModelConfig, params, batch: int, max_seq: int, *,
                       dtype=jnp.bfloat16, impl: Impl = Impl(),
                       enc_out: Optional[jnp.ndarray] = None):
-    s = cfg.ssm
-    if cfg.family == "ssm":
-        one = kvcache.init_ssm_state(batch, cfg.ssm_heads, s.head_dim, s.d_state,
-                                     s.conv_width,
-                                     cfg.d_inner + 2 * s.n_groups * s.d_state, dtype)
-        caches = kvcache.stack_caches([one] * cfg.num_layers)
+    extra = {}
+    if cfg.layer_types:
+        n_attn = cfg.layer_types.count("attention")
+        caches = {}
+        if cfg.num_layers - n_attn:
+            caches["mamba"] = kvcache.stack_caches(
+                [_ssm_state_spec(cfg, batch, dtype)] * (cfg.num_layers - n_attn))
+        if n_attn:
+            caches["attn"] = kvcache.stack_caches(
+                [_attn_cache_spec(cfg, batch, max_seq, dtype)] * n_attn)
+        # routed (token, expert) choices a layer: one column per held
+        # expert, the last for the experts held elsewhere; counted over
+        # the rows that ``occupied`` marks
+        extra = {"expert_load": jnp.zeros(
+                     (cfg.num_layers, cfg.moe.held + 1), jnp.int32),
+                 "occupied": jnp.ones((batch,), jnp.int32)}
+    elif cfg.family == "ssm":
+        caches = kvcache.stack_caches(
+            [_ssm_state_spec(cfg, batch, dtype)] * cfg.num_layers)
     elif cfg.family == "hybrid":
-        one = kvcache.init_ssm_state(batch, cfg.ssm_heads, s.head_dim, s.d_state,
-                                     s.conv_width,
-                                     cfg.d_inner + 2 * s.n_groups * s.d_state, dtype)
         n_seg = cfg.num_layers // cfg.attn_every
         attn_one = _attn_cache_spec(cfg, batch, max_seq, dtype)
         caches = {
-            "mamba": kvcache.stack_caches([one] * cfg.num_layers),
+            "mamba": kvcache.stack_caches(
+                [_ssm_state_spec(cfg, batch, dtype)] * cfg.num_layers),
             "attn": kvcache.stack_caches([attn_one] * n_seg),
         }
     elif cfg.enc_dec:
@@ -202,14 +233,15 @@ def init_decode_state(cfg: ModelConfig, params, batch: int, max_seq: int, *,
     else:
         one = _attn_cache_spec(cfg, batch, max_seq, dtype)
         caches = kvcache.stack_caches([one] * cfg.num_layers)
-    return {"caches": caches, "pos": jnp.int32(0)}
+    return {"caches": caches, "pos": jnp.int32(0), **extra}
 
 
 def decode_step(cfg: ModelConfig, params, state, token, *, impl: Impl = Impl(),
                 dtype=jnp.bfloat16):
     """token (B,1) i32 at position state["pos"] → (logits (B,1,V) f32, state)."""
     pos = state["pos"]
-    x = embed_tokens(params["embed"], token, dtype)
+    x = _embed(cfg, params, token, dtype)
+    state = dict(state)
 
     if cfg.enc_dec:
         half = cfg.d_model // 2
@@ -224,6 +256,12 @@ def decode_step(cfg: ModelConfig, params, state, token, *, impl: Impl = Impl(),
             cfg, params["blocks"],
             {"self": caches["self"], "cross": caches["cross"]}, x, pos, impl=impl)
         new_caches = {"self": new_caches["self"], "cross": caches["cross"]}
+    elif cfg.layer_types:
+        x, new_caches, load = tf.decode_pattern_stack(
+            cfg, params["blocks"], state["caches"], x, pos, impl=impl)
+        occupied = state["occupied"].astype(load.dtype)[None, :, None, None]
+        state["expert_load"] = state["expert_load"] + jnp.sum(
+            load * occupied, axis=(1, 2)).astype(jnp.int32)
     elif cfg.family == "hybrid":
         x, new_caches = tf.decode_hybrid_stack(cfg, params["blocks"],
                                                params["shared_attn"],
@@ -235,4 +273,4 @@ def decode_step(cfg: ModelConfig, params, state, token, *, impl: Impl = Impl(),
 
     x = apply_norm(cfg, params["final_norm"], x)
     logits = lm_logits(cfg, params["embed"], x)
-    return logits, {"caches": new_caches, "pos": pos + 1}
+    return logits, dict(state, caches=new_caches, pos=pos + 1)

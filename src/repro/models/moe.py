@@ -1,4 +1,7 @@
-"""Top-k routed mixture-of-experts FFN (GShard/Switch-style dense dispatch).
+"""Mixture-of-experts FFNs: the capacity layer of the ``moe`` family, and
+the dropless layer that holds a share of the experts (pattern hybrid).
+
+Capacity layer: top-k routed, GShard/Switch-style dense dispatch.
 
 Dispatch/combine are expressed as einsums against a (T, E, C) one-hot
 dispatch tensor — the formulation XLA SPMD partitions well (dispatch
@@ -7,6 +10,15 @@ EP all_to_all variant is the MPKLink-fabric hillclimb, core/fabric.py).
 
 Capacity: C = ceil(capacity_factor · T · k / E); overflow tokens drop to the
 residual path (standard). Aux losses: Switch load-balance + router z-loss.
+
+Dropless layer (``init_held_experts`` / ``apply_held_experts``): routes
+each token over all ``num_experts`` (top-k of the router logits, a softmax
+over those k), and computes the part of the result that the experts this
+chip holds give, dropping nothing; every held expert runs on every token,
+weighted by a gate that is zero where the token did not choose it, which
+reads each held expert's weights once. With every expert held it is the
+whole layer; with a share, the other shares' parts are what the chips
+holding them add. A shared expert, if any, runs on every token.
 """
 from __future__ import annotations
 
@@ -16,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import dense_init, activation
+from repro.models.layers import activation, apply_mlp, dense_init, init_mlp
 
 
 def init_moe(cfg: ModelConfig, key):
@@ -112,3 +124,57 @@ def apply_moe(cfg: ModelConfig, p, x) -> Tuple[jnp.ndarray, dict]:
     y, aux = jax.vmap(per_group)(xg)
     aux = {k: jnp.mean(v) for k, v in aux.items()}
     return y.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless layer over a held share of the experts
+# ---------------------------------------------------------------------------
+
+def init_held_experts(cfg: ModelConfig, key):
+    m = cfg.moe
+    D, F, E = cfg.d_model, cfg.d_ff, m.held
+    ks = jax.random.split(key, 5)
+    p = {
+        "router": dense_init(ks[0], (D, m.num_experts)),
+        "gate": dense_init(ks[1], (E, D, F), in_axis_size=D),
+        "up": dense_init(ks[2], (E, D, F), in_axis_size=D),
+        "down": dense_init(ks[3], (E, F, D), in_axis_size=F),
+    }
+    if m.shared_d_ff:
+        p["shared"] = init_mlp(cfg, ks[4], d_ff=m.shared_d_ff)
+    return p
+
+
+def route_top_k(cfg: ModelConfig, router, x):
+    """x (..., D) → gates (..., num_experts) f32: a softmax over each
+    token's top-k router logits, zero for the experts it did not choose."""
+    m = cfg.moe
+    logits = (x @ router.astype(x.dtype)).astype(jnp.float32)
+    top_v, top_e = jax.lax.top_k(logits, m.top_k)
+    w = jax.nn.softmax(top_v, axis=-1)
+    return jnp.sum(jax.nn.one_hot(top_e, m.num_experts, dtype=jnp.float32)
+                   * w[..., None], axis=-2)
+
+
+def apply_held_experts(cfg: ModelConfig, p, x):
+    """x (..., D) → (out (..., D), load (..., held + 1) f32): ``load``
+    counts each token's routed choices, one column per held expert and
+    the last for the experts held elsewhere."""
+    m = cfg.moe
+    act = activation(cfg.act)
+    lo, hi = m.first_expert, m.first_expert + m.held
+    with jax.named_scope("moe.router"):
+        gates = route_top_k(cfg, p["router"], x)
+        chosen = (gates > 0).astype(jnp.float32)
+        load = jnp.concatenate(
+            [chosen[..., lo:hi],
+             m.top_k - jnp.sum(chosen[..., lo:hi], -1, keepdims=True)], -1)
+    with jax.named_scope("moe.experts"):
+        g = act(jnp.einsum("...d,edf->...ef", x, p["gate"].astype(x.dtype)))
+        h = g * jnp.einsum("...d,edf->...ef", x, p["up"].astype(x.dtype))
+        h = h * gates[..., lo:hi, None].astype(x.dtype)
+        out = jnp.einsum("...ef,efd->...d", h, p["down"].astype(x.dtype))
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + apply_mlp(cfg, p["shared"], x)
+    return out, load

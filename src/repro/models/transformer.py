@@ -11,7 +11,9 @@ Families:
   moe       : [attn → moe-ffn] × L (+ aux losses accumulated through the scan)
   ssm       : [mamba2] × L
   hybrid    : segments of ``attn_every`` mamba blocks with a SHARED attention
-              block applied between segments (zamba2)
+              block applied between segments (zamba2); or, with
+              ``layer_types``, one mixer a layer (mamba or attention) and the
+              dropless held-expert FFN after every mixer (granite-4.0-h)
   audio     : encoder [attn → ffn] × Le, decoder [self → cross → ffn] × Ld
 """
 from __future__ import annotations
@@ -244,6 +246,129 @@ def decode_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, caches,
         "attn": new_attn,
     }
     return x, new_caches
+
+
+# ---------------------------------------------------------------------------
+# pattern hybrid (layer_types): mixer + held-expert FFN in every layer
+# ---------------------------------------------------------------------------
+#
+#   h  = x + r * Mixer_l(norm1_l(x))          Mixer_l: mamba or attention
+#   x' = h + r * (MoE_l + Shared_l)(norm2_l(h))          r: residual_multiplier
+#
+# Parameters: ``ln1``, ``ln2`` and ``ffn`` stacked over all L layers,
+# ``mamba`` and ``attn`` each over the layers of its kind. Each run of
+# consecutive layers of one kind is one scan whose body reads its layer's
+# weights (and its cache) by index: no slice of a stack is copied.
+
+def layer_runs(layer_types):
+    """→ [(kind, first layer, first index among that kind's layers, count)]
+    for each run of consecutive layers of one kind."""
+    runs, seen = [], {"mamba": 0, "attention": 0}
+    for i, kind in enumerate(layer_types):
+        if runs and runs[-1][0] == kind:
+            k, l0, j0, n = runs[-1]
+            runs[-1] = (k, l0, j0, n + 1)
+        else:
+            runs.append((kind, i, seen[kind], 1))
+        seen[kind] += 1
+    return runs
+
+
+_MIXER = {"mamba": "mamba", "attention": "attn"}
+
+
+def init_pattern_stack(cfg: ModelConfig, key):
+    L = cfg.num_layers
+    n_attn = cfg.layer_types.count("attention")
+    ks = jax.random.split(key, 5)
+    blocks = {
+        "ln1": init_stack(cfg, ks[0], L, lambda k: init_norm(cfg, k)),
+        "ln2": init_stack(cfg, ks[1], L, lambda k: init_norm(cfg, k)),
+        "ffn": init_stack(cfg, ks[2], L,
+                          lambda k: moe_mod.init_held_experts(cfg, k)),
+    }
+    if L - n_attn:
+        blocks["mamba"] = init_stack(cfg, ks[3], L - n_attn,
+                                     lambda k: ssm_mod.init_mamba(cfg, k))
+    if n_attn:
+        blocks["attn"] = init_stack(cfg, ks[4], n_attn,
+                                    lambda k: attn_mod.init_attn(cfg, k))
+    return blocks
+
+
+def _at(tree, i):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _pattern_ffn(cfg: ModelConfig, blocks, i, h):
+    """The layer's feed-forward on h → (h', load (..., held + 1))."""
+    u = apply_norm(cfg, _at(blocks["ln2"], i), h)
+    y, load = moe_mod.apply_held_experts(cfg, _at(blocks["ffn"], i), u)
+    return h + cfg.residual_multiplier * y, load
+
+
+def _run_index(l0, j0, n):
+    return (jnp.arange(l0, l0 + n), jnp.arange(j0, j0 + n))
+
+
+def apply_pattern_stack(cfg: ModelConfig, blocks, x, *, positions, impl: Impl):
+    """Full-sequence pattern hybrid (train / prefill)."""
+    for kind, l0, j0, n in layer_runs(cfg.layer_types):
+        def body(h, idx, kind=kind):
+            i, j = idx
+            h = impl.anchor(h)
+            u = apply_norm(cfg, _at(blocks["ln1"], i), h)
+            p = _at(blocks[_MIXER[kind]], j)
+            with jax.named_scope(kind):
+                if kind == "mamba":
+                    y = ssm_mod.apply_mamba(cfg, p, u, impl=impl.ssd)
+                else:
+                    y = attn_mod.apply_attn(
+                        cfg, p, u, positions=positions, causal=True,
+                        impl=impl.attention, q_chunk=impl.q_chunk,
+                        kv_chunk=impl.kv_chunk)
+            h, _ = _pattern_ffn(cfg, blocks, i, h + cfg.residual_multiplier * y)
+            return h, None
+
+        if impl.remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        x, _ = jax.lax.scan(body, impl.anchor(x), _run_index(l0, j0, n))
+    return x, zero_aux(cfg)
+
+
+def decode_pattern_stack(cfg: ModelConfig, blocks, caches, x, pos, *,
+                         impl: Impl):
+    """caches = {"mamba": SSM states stacked over the mamba layers, "attn":
+    KV caches stacked over the attention layers} → (x, new caches, load
+    (L, B, 1, held + 1)): each layer's routed choices per token."""
+    caches, loads = dict(caches), []
+    for kind, l0, j0, n in layer_runs(cfg.layer_types):
+        name = _MIXER[kind]
+
+        def body(carry, idx, kind=kind, name=name):
+            h, c = carry
+            i, j = idx
+            u = apply_norm(cfg, _at(blocks["ln1"], i), h)
+            p, cj = _at(blocks[name], j), _at(c, j)
+            with jax.named_scope(kind):
+                if kind == "mamba":
+                    y, cj = ssm_mod.decode_mamba(cfg, p, u, cj)
+                else:
+                    y, cj = attn_mod.decode_attn(
+                        cfg, p, u, cj, pos, impl=impl.decode_attention,
+                        kv_chunk=impl.kv_chunk)
+            c = jax.tree.map(
+                lambda a, b: jax.lax.dynamic_update_index_in_dim(
+                    a, b.astype(a.dtype), j, 0), c, cj)
+            h, load = _pattern_ffn(cfg, blocks, i,
+                                   h + cfg.residual_multiplier * y)
+            return (h, c), load
+
+        (x, caches[name]), load = jax.lax.scan(
+            body, (x, caches[name]), _run_index(l0, j0, n))
+        loads.append(load)
+    return x, caches, jnp.concatenate(loads)
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,11 @@ high-throughput decode. Prompt tokens are fed incrementally through the
 same decode path (teacher-forced), then generation continues from the
 model's samples until EOS/max_new.
 
-Works for dense and SSM families (per-slot positions; ring caches need
-uniform positions and are served by the batch path / dry-run cells).
+Serves every configuration whose caches take per-slot positions: dense
+KV caches, SSM states, and both side by side in the hybrids (ring caches
+need uniform positions and are served by the batch path / dry-run cells).
+A model with held experts also counts, on the device, the routed choices
+of the occupied slots (``expert_load``).
 """
 from __future__ import annotations
 
@@ -81,6 +84,8 @@ class ServingEngine:
             state = init_decode_state(cfg, self.params, max_batch, max_seq,
                                       dtype=dtype, impl=impl)
             state["pos"] = jnp.zeros((max_batch,), jnp.int32)
+            if "occupied" in state:          # the expert-load counter's mask
+                state["occupied"] = jnp.zeros((max_batch,), jnp.int32)
         self.state = jax.device_put(state, device)
 
         def engine_decode_step(p, s, t):    # its module: jit_engine_decode_step
@@ -131,6 +136,7 @@ class ServingEngine:
                         lambda c: c.at[:, b].set(0) if c.ndim >= 2 else c,
                         self.state["caches"])
                     self.state["pos"] = self.state["pos"].at[b].set(0)
+                    self._mark_occupied(b, 1)
                 self.current_token[b, 0] = req.prompt[0]
                 self.prompt_cursor[b] = 1
 
@@ -140,6 +146,19 @@ class ServingEngine:
         req.finished_at = time.perf_counter()
         self.completed.append(req)
         self.slots[b] = None
+        self._mark_occupied(b, 0)
+
+    def _mark_occupied(self, b: int, flag: int):
+        if "occupied" in self.state:
+            self.state["occupied"] = self.state["occupied"].at[b].set(flag)
+
+    def expert_load(self) -> Optional[np.ndarray]:
+        """Routed (token, expert) choices of the occupied slots since the
+        engine was made: (layers, held experts + 1), the last column for
+        the experts held elsewhere; None for a model without held experts.
+        A blocking device read, so never called inside the tick."""
+        load = self.state.get("expert_load")
+        return None if load is None else np.asarray(load)
 
     # -- engine tick ---------------------------------------------------------
     def tick(self):
@@ -217,6 +236,9 @@ class ServingEngine:
         self.prompt_cursor[:] = 0
         self.state["pos"] = jax.device_put(
             np.zeros((self.B,), np.int32), self.device)
+        if "occupied" in self.state:
+            self.state["occupied"] = jax.device_put(
+                np.zeros((self.B,), np.int32), self.device)
         return lost
 
 

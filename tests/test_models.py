@@ -66,7 +66,8 @@ def test_decode_runs(arch):
 # reproduce the full-sequence forward logits for every family with a cache.
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "mamba2-1.3b", "zamba2-2.7b",
                                   "whisper-tiny", "mixtral-8x7b",
-                                  "llava-next-mistral-7b"])
+                                  "llava-next-mistral-7b",
+                                  "granite-4.0-h-small"])
 def test_decode_matches_forward(arch):
     cfg = get_reduced(arch)
     if cfg.moe:

@@ -176,3 +176,33 @@ def test_olmo_decode_step_fits_one_chip(chip):
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert live < HBM_BYTES, (live, m)
+
+
+def test_granite_decode_step_fits_one_chip(chip):
+    """granite-4.0-h-small's decode step at one chip's cut (layers 0-9,
+    experts 0-8 of 72, 16 slots x 512 positions, f32), as the engine jits
+    it, compiles for v5e and fits one chip's 16 GiB."""
+    from repro.configs import get_config, replace
+    from repro.models import decode_step, init_decode_state, init_params
+    from repro.models.transformer import Impl
+    full = get_config("granite-4.0-h-small")
+    cfg = replace(full, num_layers=10, layer_types=full.layer_types[:10],
+                  moe=replace(full.moe, held_experts=9))
+    dtype, impl, B, S = jnp.float32, Impl(remat=False), 16, 512
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+
+    def state_of(p):
+        st = init_decode_state(cfg, p, B, S, dtype=dtype, impl=impl)
+        st["pos"] = jnp.zeros((B,), jnp.int32)
+        return st
+
+    state = jax.eval_shape(state_of, params)
+    on_chip = lambda t: jax.tree.map(lambda a: chip(a.shape, a.dtype), t)
+    compiled = _compile(
+        lambda p, s, t: decode_step(cfg, p, s, t, impl=impl, dtype=dtype),
+        on_chip(params), on_chip(state), chip((B, 1), jnp.int32))
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 9.5e9 < m.argument_size_in_bytes and live < HBM_BYTES, (live, m)
