@@ -119,13 +119,16 @@ def prefill_attn(cfg: ModelConfig, p, x, cache, *, positions, use_rope=True,
 
 
 def decode_attn(cfg: ModelConfig, p, x_new, cache, pos, *, use_rope=True,
-                impl="naive", cross=False, kv_chunk=1024):
+                impl="naive", cross=False, kv_chunk=1024, layer=None):
     """Single-token decode. x_new (B,1,D); ``pos`` = index of the new token —
     scalar int32 (uniform batch: the dry-run/serve_step fast path) or (B,)
     per-slot positions (continuous batching). Dense cache → insert then
     attend over valid slots; ring cache → insert at pos % W with absolute
     slot positions doing the masking (scalar pos only).
-    ``cross=True`` skips insertion (static encoder KV)."""
+    ``cross=True`` skips insertion (static encoder KV).
+    ``layer`` given: ``cache`` stacks every layer's, the new rows go into
+    that layer of it and attention reads the layer by index; the whole
+    stack is returned."""
     B = x_new.shape[0]
     per_slot = getattr(pos, "ndim", 0) == 1
     q, k, v = _project_qkv(cfg, p, x_new)
@@ -147,18 +150,21 @@ def decode_attn(cfg: ModelConfig, p, x_new, cache, pos, *, use_rope=True,
 
     if "slot_pos" in cache:                       # SWA ring buffer
         assert not per_slot, "ring caches require uniform decode positions"
-        cache = kvcache.ring_cache_insert(cache, k, v, pos)
-        kv_pos = jnp.broadcast_to(cache["slot_pos"][None], (B, cache["k"].shape[1]))
+        cache = kvcache.ring_cache_insert(cache, k, v, pos, layer)
+        kv = kvcache.cache_layer(cache, layer)
+        kv_pos = jnp.broadcast_to(kv["slot_pos"][None], (B, kv["k"].shape[1]))
     elif per_slot:                                # dense, continuous batching
-        cache = kvcache.dense_cache_insert_rows(cache, k, v, pos)
-        kv_pos = kvcache.dense_cache_positions_rows(cache, pos + 1)
+        cache = kvcache.dense_cache_insert_rows(cache, k, v, pos, layer)
+        kv = kvcache.cache_layer(cache, layer)
+        kv_pos = kvcache.dense_cache_positions_rows(kv, pos + 1)
     else:                                         # dense, uniform
-        cache = kvcache.dense_cache_insert(cache, k, v, pos)
+        cache = kvcache.dense_cache_insert(cache, k, v, pos, layer)
+        kv = kvcache.cache_layer(cache, layer)
         kv_pos = jnp.broadcast_to(
-            kvcache.dense_cache_positions(cache, pos + 1)[None],
-            (B, cache["k"].shape[1]))
+            kvcache.dense_cache_positions(kv, pos + 1)[None],
+            (B, kv["k"].shape[1]))
 
-    o = kops.attention(q, cache["k"].astype(q.dtype), cache["v"].astype(q.dtype),
+    o = kops.attention(q, kv["k"].astype(q.dtype), kv["v"].astype(q.dtype),
                        q_pos, kv_pos, causal=True, window=cfg.swa_window,
                        impl=impl, kv_chunk=kv_chunk)
     return _out_proj(p, o), cache

@@ -178,16 +178,20 @@ def apply_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, x, *,
 # ---------------------------------------------------------------------------
 
 def decode_block(cfg: ModelConfig, p, x, cache, pos, *, impl: Impl,
-                 use_rope=True):
-    """Returns (x, new_cache)."""
+                 use_rope=True, layer=None):
+    """Returns (x, new_cache). ``layer`` given: ``cache`` is the whole
+    stack, read and written at that layer, and the stack is returned."""
     if cfg.family == "ssm":
-        h, new_state = ssm_mod.decode_mamba(cfg, p["mamba"],
-                                            apply_norm(cfg, p["ln1"], x), cache)
+        h, new_state = ssm_mod.decode_mamba(
+            cfg, p["mamba"], apply_norm(cfg, p["ln1"], x),
+            kvcache.cache_layer(cache, layer))
+        if layer is not None:
+            new_state = kvcache.cache_put_layer(cache, new_state, layer)
         return x + h, new_state
     h, new_cache = attn_mod.decode_attn(cfg, p["attn"], apply_norm(cfg, p["ln1"], x),
                                         cache, pos, use_rope=use_rope,
                                         impl=impl.decode_attention,
-                                        kv_chunk=impl.kv_chunk)
+                                        kv_chunk=impl.kv_chunk, layer=layer)
     x = x + h
     h = apply_norm(cfg, p["ln2"], x)
     if cfg.moe:
@@ -199,15 +203,19 @@ def decode_block(cfg: ModelConfig, p, x, cache, pos, *, impl: Impl,
 
 def decode_stack(cfg: ModelConfig, stacked, caches, x, pos, *, impl: Impl,
                  use_rope=True):
-    """Scan the layer stack carrying the token activation, emitting new caches."""
-    def body(h, inp):
-        layer_p, cache_l = inp
-        h, new_cache = decode_block(cfg, layer_p, h, cache_l, pos, impl=impl,
-                                    use_rope=use_rope)
-        return h, new_cache
+    """Scan the layer stack carrying the token activation and the stacked
+    caches: layer l reads and writes its own cache by index (a KV cache
+    takes only its new rows), so with the state donated every write is in
+    place and no layer of the cache is copied."""
+    def body(carry, inp):
+        h, c = carry
+        layer_p, l = inp
+        return decode_block(cfg, layer_p, h, c, pos, impl=impl,
+                            use_rope=use_rope, layer=l), None
 
-    x, new_caches = jax.lax.scan(body, x, (stacked, caches))
-    return x, new_caches
+    layers = jnp.arange(jax.tree.leaves(stacked)[0].shape[0])
+    (x, caches), _ = jax.lax.scan(body, (x, caches), (stacked, layers))
+    return x, caches
 
 
 def decode_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, caches,
@@ -350,17 +358,16 @@ def decode_pattern_stack(cfg: ModelConfig, blocks, caches, x, pos, *,
             h, c = carry
             i, j = idx
             u = apply_norm(cfg, _at(blocks["ln1"], i), h)
-            p, cj = _at(blocks[name], j), _at(c, j)
+            p = _at(blocks[name], j)
             with jax.named_scope(kind):
                 if kind == "mamba":
-                    y, cj = ssm_mod.decode_mamba(cfg, p, u, cj)
+                    y, cj = ssm_mod.decode_mamba(
+                        cfg, p, u, kvcache.cache_layer(c, j))
+                    c = kvcache.cache_put_layer(c, cj, j)
                 else:
-                    y, cj = attn_mod.decode_attn(
-                        cfg, p, u, cj, pos, impl=impl.decode_attention,
-                        kv_chunk=impl.kv_chunk)
-            c = jax.tree.map(
-                lambda a, b: jax.lax.dynamic_update_index_in_dim(
-                    a, b.astype(a.dtype), j, 0), c, cj)
+                    y, c = attn_mod.decode_attn(
+                        cfg, p, u, c, pos, impl=impl.decode_attention,
+                        kv_chunk=impl.kv_chunk, layer=j)
             h, load = _pattern_ffn(cfg, blocks, i,
                                    h + cfg.residual_multiplier * y)
             return (h, c), load

@@ -1,8 +1,9 @@
 """Serving engine: continuous batching over a fixed slot grid.
 
 Requests (prompts) occupy slots of a size-B decode batch; every engine tick
-runs ONE jitted decode_step for all slots with per-slot positions (the
-per-slot KV insert is kvcache.dense_cache_insert_rows). New requests join
+runs ONE jitted decode_step for all slots with per-slot positions, its
+state donated so that each layer's new KV rows are written in place
+(kvcache.dense_cache_insert_rows into the carried stack). New requests join
 as slots free up — no batch-wide barrier, the production pattern for
 high-throughput decode. Prompt tokens are fed incrementally through the
 same decode path (teacher-forced), then generation continues from the
@@ -77,20 +78,13 @@ class ServingEngine:
         self.greedy = greedy
         self.key = jax.device_put(jax.random.PRNGKey(seed), device)
 
-        # made on the device, then committed to it: an uncommitted cache
-        # would let the first admission's un-jitted row reset run on the
-        # default device (a copy of the whole cache on device 0 per replica)
-        with jax.default_device(device):
-            state = init_decode_state(cfg, self.params, max_batch, max_seq,
-                                      dtype=dtype, impl=impl)
-            state["pos"] = jnp.zeros((max_batch,), jnp.int32)
-            if "occupied" in state:          # the expert-load counter's mask
-                state["occupied"] = jnp.zeros((max_batch,), jnp.int32)
-        self.state = jax.device_put(state, device)
+        self.state = self._fresh_state()
 
         def engine_decode_step(p, s, t):    # its module: jit_engine_decode_step
             return decode_step(cfg, p, s, t, impl=impl, dtype=dtype)
-        self._step = jax.jit(engine_decode_step)
+        # the state is donated: the step writes its caches in place, and
+        # the caller's state is gone once the step is dispatched
+        self._step = jax.jit(engine_decode_step, donate_argnums=(1,))
 
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.queue: List[Request] = []
@@ -109,6 +103,28 @@ class ServingEngine:
          self._sp_dispatch, self._sp_sample, self._sp_bookkeep) = (
             f"engine.{phase}:{device.id}" for phase in
             ("tick", "admit", "slot_reset", "dispatch", "sample", "bookkeep"))
+
+    def _fresh_state(self):
+        """An empty decode state, made on the engine's device and committed
+        to it: an uncommitted cache would let the first admission's
+        un-jitted row reset run on the default device (a copy of the whole
+        cache on device 0 per replica)."""
+        with jax.default_device(self.device):
+            state = init_decode_state(self.cfg, self.params, self.B,
+                                      self.max_seq, dtype=self.dtype,
+                                      impl=self.impl)
+            state["pos"] = jnp.zeros((self.B,), jnp.int32)
+            if "occupied" in state:          # the expert-load counter's mask
+                state["occupied"] = jnp.zeros((self.B,), jnp.int32)
+        return jax.device_put(state, self.device)
+
+    def _state_lost(self) -> bool:
+        """The state was donated to a step whose output never came back: a
+        step that failed after dispatch, or a caller of ``_step`` that kept
+        its input in place of the output. Its buffers are gone; the engine
+        starts again from an empty state, and any slot still in flight
+        continues without what the lost state held."""
+        return any(a.is_deleted() for a in jax.tree.leaves(self.state))
 
     # -- request management -----------------------------------------------
     def submit(self, req: Request):
@@ -167,6 +183,8 @@ class ServingEngine:
         span = telemetry.span
         with span(self._sp_tick):
             with span(self._sp_admit):
+                if self._state_lost():
+                    self.state = self._fresh_state()
                 self._admit()
                 if all(s is None for s in self.slots):
                     return False
@@ -227,13 +245,16 @@ class ServingEngine:
 
     def reset(self) -> List[Request]:
         """Crash recovery: drop all in-flight work and return to an empty
-        slot grid (caches/positions are re-zeroed per slot on admit).
+        slot grid (caches/positions are re-zeroed per slot on admit; a
+        state lost to a failed donated step is made anew).
         → the requests that were lost (queued + slotted)."""
         lost = [r for r in self.slots if r is not None] + list(self.queue)
         self.slots = [None] * self.B
         self.queue = []
         self.current_token[:] = 0
         self.prompt_cursor[:] = 0
+        if self._state_lost():
+            self.state = self._fresh_state()
         self.state["pos"] = jax.device_put(
             np.zeros((self.B,), np.int32), self.device)
         if "occupied" in self.state:

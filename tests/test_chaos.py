@@ -386,6 +386,55 @@ def test_engine_service_recovers_from_midflight_crash():
     assert svc2.crashes == 1 and engine.queue == []
 
 
+def test_engine_service_recovers_from_a_step_that_failed_after_donation():
+    """The step donates the engine's state: one that dies after dispatch
+    leaves the caches deleted. Recovery makes the state anew, and the
+    engine then serves exactly what a fresh engine serves."""
+    import jax
+    from repro.configs import get_reduced
+    from repro.models import init_params
+    from repro.models.transformer import Impl
+    from repro.runtime import (EngineService, Request, ServingEngine,
+                               encode_prompt)
+
+    cfg = get_reduced("llama3.2-1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+
+    def engine():
+        return ServingEngine(cfg, params, max_batch=2, max_seq=32,
+                             impl=Impl(attention="naive", remat=False))
+
+    eng = engine()
+    step, donated = eng._step, []
+
+    def dies_after_dispatch(p, s, t):
+        step(p, s, t)
+        donated.append(all(a.is_deleted() for a in jax.tree.leaves(s)))
+        raise RuntimeError("device step failed")
+
+    eng._step = dies_after_dispatch
+    svc = EngineService(eng, timeout=60.0).start()
+    prompts = [[4, 5], [7, 1, 2], [3]]
+    try:
+        with pytest.raises(ServiceCrashed):
+            svc.handler(encode_prompt([1, 2, 3], max_new=4))
+        assert donated == [True] and svc.crashes == 1
+        eng._step = step
+        served = svc.handler_batch([encode_prompt(p, max_new=5)
+                                    for p in prompts])
+    finally:
+        svc.close()
+    assert not any(a.is_deleted() for a in jax.tree.leaves(eng.state))
+
+    fresh = engine()
+    reqs = [Request(rid=i, prompt=p, max_new=5) for i, p in enumerate(prompts)]
+    for r in reqs:
+        fresh.submit(r)
+    fresh.run_until_drained()
+    assert [list(np.asarray(o)) for o in served] == \
+        [r.generated for r in reqs]
+
+
 # ---------------------------------------------------------------------------
 # supervisor wiring: gateway health → heartbeat view → recovery plan
 # ---------------------------------------------------------------------------

@@ -1,0 +1,163 @@
+"""The engine's decode step updates its state in place: the state is
+donated, the caches are carried through the layer loop, and a dense cache
+takes only its new rows. Checked on the compiled step (aliasing, no copy of
+a layer or of the stack) and on the tokens served, against the plain walk
+that scans the caches as inputs and emits new ones. Tiny models on the CPU."""
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from repro.configs import get_reduced
+from repro.models import init_params
+from repro.models import transformer as tf
+from repro.models.transformer import Impl
+from repro.runtime import Request, ServingEngine
+
+IMPL = Impl(attention="naive", remat=False)
+B, MAX_SEQ = 4, 64
+
+
+def _model(arch):
+    cfg = get_reduced(arch)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module", params=["olmo-1b", "mamba2-1.3b",
+                                        "granite-4.0-h-small"])
+def model(request):
+    return _model(request.param)
+
+
+@pytest.fixture(scope="module", params=["olmo-1b", "mamba2-1.3b"])
+def stacked_model(request):
+    """A model whose layers are one stack (dense, SSM)."""
+    return _model(request.param)
+
+
+def _engine(model, **kw):
+    cfg, params = model
+    return ServingEngine(cfg, params, max_batch=kw.pop("max_batch", B),
+                         max_seq=kw.pop("max_seq", MAX_SEQ), impl=IMPL, **kw)
+
+
+def _shape(a, shape=None):
+    """The array's type as HLO writes it: ``f32[2,4,64,4,16]``."""
+    kind = {"f": "f", "i": "s", "u": "u"}[a.dtype.kind]
+    dims = ",".join(map(str, a.shape if shape is None else shape))
+    return f"{kind}{a.dtype.itemsize * 8}[{dims}]"
+
+
+def _instructions(hlo):
+    """→ [(fused, root, result type, opcode)] for every instruction of the
+    compiled module; ``fused`` marks those inside a fusion's body, whose
+    results are no buffer of their own unless they are its root."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    out, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        ins = re.match(r"\s*(ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])\S* "
+                       r"([\w\-]+)\(", line)
+        if ins:
+            out.append((comp in fused, bool(ins.group(1)), ins.group(2),
+                        ins.group(3)))
+    return out
+
+
+def _aliased_params(hlo):
+    """Result types of the entry parameters that an output aliases."""
+    header = hlo.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\}: \((\d+), \{", header)}
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    params = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\((\d+)\)", entry)
+    return sorted(t for t, n in params if int(n) in aliased)
+
+
+def test_step_aliases_its_state_and_copies_no_layer(model):
+    cfg, _ = model
+    eng = _engine(model)
+    hlo = eng._step.lower(eng.params, eng.state,
+                          np.zeros((B, 1), np.int32)).compile().as_text()
+    named = jax.tree_util.tree_leaves_with_path(eng.state["caches"])
+    caches = [a for _, a in named]
+    aliased = _aliased_params(hlo)
+    for leaf in caches:                       # every cache leaf written back
+        assert _shape(leaf) in aliased, (_shape(leaf), aliased)
+    assert len(aliased) >= len(caches)
+    # XLA's CPU compiler copies the stack of SSM conv tails: fusions it
+    # schedules after the in-place write re-read the old window. The chip's
+    # compiler does not (test_tpu_compile checks every stack, at the cells'
+    # size); every other stack is checked here too.
+    stacks = {_shape(a) for path, a in named
+              if "conv" not in jax.tree_util.keystr(path)}
+    ins = _instructions(hlo)
+    copies = [t for _, _, t, op in ins if op in ("copy", "copy-start")]
+    assert not stacks & set(copies), copies
+    if cfg.family == "dense":
+        k = eng.state["caches"]["k"]
+        whole = {_shape(k), _shape(k, (1,) + k.shape[1:])}
+        # no buffer holds a layer of the cache, and nothing writes one:
+        # the layer is read inside the reductions, the rows scattered
+        for in_fusion, root, t, op in ins:
+            if op == "dynamic-update-slice" or (
+                    op == "dynamic-slice" and (root or not in_fusion)):
+                assert t not in whole, (t, op)
+
+
+def _old_decode_stack(cfg, stacked, caches, x, pos, *, impl, use_rope=True):
+    """The plain walk: the caches scanned as inputs, new ones emitted."""
+    def body(h, inp):
+        layer_p, cache_l = inp
+        return tf.decode_block(cfg, layer_p, h, cache_l, pos, impl=impl,
+                               use_rope=use_rope)
+
+    return jax.lax.scan(body, x, (stacked, caches))
+
+
+def _requests(vocab):
+    """More requests than slots, of mixed lengths, arriving over time: slots
+    are taken and freed on different ticks."""
+    shapes = [(3, 9), (1, 4), (12, 20), (5, 3), (2, 11), (9, 9), (4, 1),
+              (6, 14), (7, 6), (1, 17)]
+    return [Request(rid=i, prompt=[(7 * i + j) % (vocab - 1) + 1
+                                   for j in range(n)], max_new=m)
+            for i, (n, m) in enumerate(shapes)]
+
+
+def _serve(eng, vocab):
+    """Serve the requests, two joining every third tick → (tokens a
+    request, tick each retired on, host syncs each tick)."""
+    reqs = _requests(vocab)
+    pending, retired_at, syncs = list(reqs), {}, []
+    for t in range(1000):
+        if not (pending or eng.queue or any(eng.slots)):
+            break
+        if t % 3 == 0:
+            for r in pending[:2]:
+                eng.submit(r)
+            del pending[:2]
+        before = eng.host_syncs
+        if eng.tick():
+            syncs.append(eng.host_syncs - before)
+        for r in eng.completed:
+            retired_at.setdefault(r.rid, eng.ticks)
+    return {r.rid: r.generated for r in reqs}, retired_at, syncs
+
+
+@pytest.mark.parametrize("greedy", [True, False])
+def test_in_place_serves_the_plain_walks_tokens(stacked_model, greedy,
+                                                 monkeypatch):
+    cfg, _ = stacked_model
+    kw = dict(greedy=greedy, seed=2147483713, max_batch=3)
+    got = _serve(_engine(stacked_model, **kw), cfg.vocab_size)
+    monkeypatch.setattr(tf, "decode_stack", _old_decode_stack)
+    want = _serve(_engine(stacked_model, **kw), cfg.vocab_size)
+    assert got[0] == want[0]
+    assert got[1] == want[1] and len(got[1]) == len(_requests(2))
+    assert len(got[2]) >= 40
+    assert got[2] == [1] * len(got[2])         # one host sync a tick

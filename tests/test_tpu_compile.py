@@ -151,14 +151,12 @@ def _chip_smoke():
     return mod
 
 
-def test_olmo_decode_step_fits_one_chip(chip):
-    """OLMo-1B's decode step at chip_smoke's serving size, as the engine
-    jits it, compiles for v5e and fits one chip's 16 GiB."""
+def _engine_step(chip, cfg, B, S, dtype=jnp.float32):
+    """The decode step as ``ServingEngine`` jits it (state donated, per-slot
+    positions), compiled for one chip → (compiled, state shapes)."""
     from repro.models import decode_step, init_decode_state, init_params
     from repro.models.transformer import Impl
-    smoke = _chip_smoke()
-    cfg, dtype, impl = _olmo(), getattr(jnp, smoke.DTYPE), Impl(remat=False)
-    B, S = smoke.MAX_BATCH, smoke.MAX_SEQ
+    impl = Impl(remat=False)
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
 
@@ -169,40 +167,70 @@ def test_olmo_decode_step_fits_one_chip(chip):
 
     state = jax.eval_shape(state_of, params)
     on_chip = lambda t: jax.tree.map(lambda a: chip(a.shape, a.dtype), t)
-    compiled = _compile(
+    step = jax.jit(
         lambda p, s, t: decode_step(cfg, p, s, t, impl=impl, dtype=dtype),
-        on_chip(params), on_chip(state), chip((B, 1), jnp.int32))
-    m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+        donate_argnums=(1,))
+    compiled = step.lower(on_chip(params), on_chip(state),
+                          chip((B, 1), jnp.int32)).compile()
+    return compiled, state
+
+
+def _live_bytes(m):
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert live < HBM_BYTES, (live, m)
+
+
+def _granite_chip_cut():
+    """granite-4.0-h-small as one chip holds it: layers 0-9, experts 0-8."""
+    from repro.configs import get_config, replace
+    full = get_config("granite-4.0-h-small")
+    return replace(full, num_layers=10, layer_types=full.layer_types[:10],
+                   moe=replace(full.moe, held_experts=9))
+
+
+def test_olmo_decode_step_fits_one_chip(chip):
+    """OLMo-1B's decode step at chip_smoke's serving size, as the engine
+    jits it, compiles for v5e and fits one chip's 16 GiB."""
+    smoke = _chip_smoke()
+    compiled, _ = _engine_step(chip, _olmo(), smoke.MAX_BATCH, smoke.MAX_SEQ,
+                               getattr(jnp, smoke.DTYPE))
+    m = compiled.memory_analysis()
+    assert _live_bytes(m) < HBM_BYTES, m
 
 
 def test_granite_decode_step_fits_one_chip(chip):
     """granite-4.0-h-small's decode step at one chip's cut (layers 0-9,
     experts 0-8 of 72, 16 slots x 512 positions, f32), as the engine jits
     it, compiles for v5e and fits one chip's 16 GiB."""
-    from repro.configs import get_config, replace
-    from repro.models import decode_step, init_decode_state, init_params
-    from repro.models.transformer import Impl
-    full = get_config("granite-4.0-h-small")
-    cfg = replace(full, num_layers=10, layer_types=full.layer_types[:10],
-                  moe=replace(full.moe, held_experts=9))
-    dtype, impl, B, S = jnp.float32, Impl(remat=False), 16, 512
-    params = jax.eval_shape(lambda k: init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-
-    def state_of(p):
-        st = init_decode_state(cfg, p, B, S, dtype=dtype, impl=impl)
-        st["pos"] = jnp.zeros((B,), jnp.int32)
-        return st
-
-    state = jax.eval_shape(state_of, params)
-    on_chip = lambda t: jax.tree.map(lambda a: chip(a.shape, a.dtype), t)
-    compiled = _compile(
-        lambda p, s, t: decode_step(cfg, p, s, t, impl=impl, dtype=dtype),
-        on_chip(params), on_chip(state), chip((B, 1), jnp.int32))
+    compiled, _ = _engine_step(chip, _granite_chip_cut(), 16, 512)
     m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert 9.5e9 < m.argument_size_in_bytes and live < HBM_BYTES, (live, m)
+    assert 9.5e9 < m.argument_size_in_bytes and _live_bytes(m) < HBM_BYTES, m
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "mamba2-1.3b",
+                                  "granite-4.0-h-small"])
+def test_decode_step_updates_its_state_in_place(chip, arch):
+    """At the benchmark cells' size and precision (16 slots x 512
+    positions, f32, matrix products at "high"), the engine's step writes
+    every cache back into the buffer it was given and copies no stacked
+    cache; the dense step holds no buffer of a layer's K or V (it writes
+    its new rows, and reads the layer inside the reductions)."""
+    from repro.configs import get_config
+    cfg = (_granite_chip_cut() if arch.startswith("granite")
+           else get_config(arch))
+    with jax.default_matmul_precision("high"):
+        compiled, state = _engine_step(chip, cfg, 16, 512)
+    m = compiled.memory_analysis()
+    caches = jax.tree.leaves(state["caches"])
+    assert m.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                        for a in caches), m
+    stacks = {f"f32[{','.join(map(str, a.shape))}]" for a in caches}
+    for line in compiled.as_text().splitlines():
+        if " copy(" in line or " copy-start(" in line:
+            # the copy's destination; one into memory space 1 (``S(1)``,
+            # the core's own memory) is a prefetch, not a second buffer
+            dest = line.split(" = ", 1)[-1].lstrip("(").split("}", 1)[0]
+            assert "S(" in dest or not any(t in dest for t in stacks), line
+    if cfg.family == "dense":
+        k = state["caches"]["k"]
+        assert m.temp_size_in_bytes < k.size // k.shape[0] * 4, m
