@@ -93,7 +93,7 @@ def demo_moe_ep():
 
 def demo_pipeline():
     cfg = replace(get_reduced("llama3.2-1b"), num_layers=8)
-    stacked = tf.init_stack(cfg, jax.random.PRNGKey(0), cfg.num_layers)
+    stacked = tf.init_stack(cfg, jax.random.PRNGKey(0))
     n_micro, mb, S = 4, 2, 16
     x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, S, cfg.d_model))
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (mb, S))

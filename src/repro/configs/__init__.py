@@ -6,7 +6,6 @@ from repro.configs.base import (
     OptimizerConfig,
     ShardingConfig,
     TrainConfig,
-    ServeConfig,
     SHAPES,
     SHAPES_BY_NAME,
     shape_applicable,
@@ -16,6 +15,6 @@ from repro.configs.registry import ARCH_IDS, get_config, get_reduced, all_cells
 
 __all__ = [
     "ModelConfig", "MoEConfig", "SSMConfig", "ShapeConfig", "OptimizerConfig",
-    "ShardingConfig", "TrainConfig", "ServeConfig", "SHAPES", "SHAPES_BY_NAME",
+    "ShardingConfig", "TrainConfig", "SHAPES", "SHAPES_BY_NAME",
     "shape_applicable", "replace", "ARCH_IDS", "get_config", "get_reduced", "all_cells",
 ]

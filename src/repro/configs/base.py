@@ -98,9 +98,9 @@ class ModelConfig:
     # (None: 1/sqrt(head_dim))
     use_rope: bool = True
     attention_multiplier: Optional[float] = None
-    # granite scalars: x0 = embed * embedding_multiplier; the pattern
-    # hybrid's mixer and feed-forward outputs are scaled by
-    # residual_multiplier; logits are divided by logits_scaling
+    # granite scalars: x0 = embed * embedding_multiplier; each layer's
+    # mixer and feed-forward outputs are scaled by residual_multiplier;
+    # logits are divided by logits_scaling
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
@@ -137,9 +137,6 @@ class ModelConfig:
                 raise ValueError(f"layer_types must name mamba or attention "
                                  f"for each of {self.num_layers} layers: "
                                  f"{kinds}")
-        elif self.residual_multiplier != 1.0:
-            raise ValueError("residual_multiplier applies to the pattern "
-                             "hybrid (layer_types) only")
 
     # -- derived ------------------------------------------------------------
     @property
@@ -149,6 +146,28 @@ class ModelConfig:
     @property
     def kv_heads_eff(self) -> int:
         return self.pad_kv_heads or self.num_kv_heads
+
+    @property
+    def mixers(self) -> Optional[Tuple[str, ...]]:
+        """Each layer's mixer, "mamba" or "attention", for a model served
+        by the layer stack; None for zamba2's shared-block hybrid and the
+        encoder-decoder, whose layers have no single kind."""
+        if self.layer_types:
+            return self.layer_types
+        if self.family == "ssm":
+            return ("mamba",) * self.num_layers
+        if self.family in ("dense", "moe", "vlm"):
+            return ("attention",) * self.num_layers
+        return None
+
+    @property
+    def ffn_kind(self) -> Optional[str]:
+        """The feed-forward after each mixer: "held" (the dropless layer
+        over this chip's share of the experts), "moe" (the capacity
+        layer), "mlp", or None."""
+        if self.moe:
+            return "held" if self.layer_types else "moe"
+        return "mlp" if self.d_ff else None
 
     @property
     def attention_free(self) -> bool:
@@ -169,7 +188,7 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Parameter count, exact for what ``init_params`` instantiates
-        (the pattern hybrid counts the experts held here)."""
+        for the layer stack (it counts the experts held here)."""
         c, D = self, self.d_model
         n = c.vocab_size * D                      # embed
         if not c.tie_embeddings:
@@ -178,6 +197,7 @@ class ModelConfig:
             c.num_heads * c.head_dim * D          # q
             + 2 * c.num_kv_heads * c.head_dim * D  # k, v
             + c.num_heads * c.head_dim * D        # o
+            + (2 * c.head_dim if c.qk_norm else 0)  # q, k norms
         )
         per_ffn = (3 if c.mlp_type == "glu" else 2) * D * c.d_ff  # (gate,) up, down
         if c.moe:
@@ -195,20 +215,17 @@ class ModelConfig:
                 + di                                                          # gate norm
             )
         norm_p = 0 if c.norm_type == "np_layernorm" else D
-        if c.layer_types:
-            n_attn = c.layer_types.count("attention")
+        if c.mixers:
+            n_attn = c.mixers.count("attention")
+            per_layer = per_ffn + 2 * norm_p if c.ffn_kind else norm_p
             n += ((c.num_layers - n_attn) * per_ssm + n_attn * per_attn
-                  + c.num_layers * (per_ffn + 2 * norm_p) + norm_p)
-        elif c.family == "ssm":
-            n += c.num_layers * (per_ssm + 2 * norm_p)
-        elif c.family == "hybrid":
-            n_attn = 1 if c.shared_attn else max(1, c.num_layers // max(1, c.attn_every))
-            n += c.num_layers * (per_ssm + 2 * norm_p) + n_attn * (per_attn + norm_p)
+                  + c.num_layers * per_layer + norm_p)
         elif c.enc_dec:
             n += c.enc_layers * (per_attn + per_ffn + 3 * norm_p)             # enc self+ffn
             n += c.num_layers * (2 * per_attn + per_ffn + 4 * norm_p)         # dec self+cross+ffn
         else:
-            n += c.num_layers * (per_attn + per_ffn + 2 * norm_p)
+            n_attn = 1 if c.shared_attn else max(1, c.num_layers // max(1, c.attn_every))
+            n += c.num_layers * (per_ssm + 2 * norm_p) + n_attn * (per_attn + norm_p)
         if c.vision_tokens:
             n += c.vision_dim * D + D
         return int(n)
@@ -258,7 +275,7 @@ def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]
 
 
 # ---------------------------------------------------------------------------
-# Training / serving / sharding knobs
+# Training / sharding knobs
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
@@ -277,10 +294,6 @@ class OptimizerConfig:
 @dataclass(frozen=True)
 class ShardingConfig:
     policy: str = "tp"            # tp | fsdp_tp
-    # MPKLink fabric switches (beyond-paper explicit-collective paths)
-    fabric_tp: bool = False       # explicit shard_map TP exchange instead of GSPMD
-    fabric_guard: bool = False    # tag+MAC guard on fabric channels
-    grad_compression: bool = False  # int8+EF on cross-pod gradient reduce
     remat: str = "block"          # none | block | full
     scan_layers: bool = True
 
@@ -296,15 +309,6 @@ class TrainConfig:
     log_every: int = 10
     checkpoint_every: int = 100
     keep_checkpoints: int = 3
-
-
-@dataclass(frozen=True)
-class ServeConfig:
-    max_batch: int = 128
-    max_seq: int = 32_768
-    dtype: str = "bfloat16"
-    sharding: ShardingConfig = field(default_factory=ShardingConfig)
-    decode_steps: int = 32
 
 
 def replace(cfg, **kw):

@@ -43,25 +43,24 @@ def init_params(cfg: ModelConfig, key):
     ks = jax.random.split(key, 8)
     params = {"embed": init_embed(cfg, ks[0]), "final_norm": init_norm(cfg, ks[1])}
 
-    if cfg.layer_types:
-        params["blocks"] = tf.init_pattern_stack(cfg, ks[2])
-    elif cfg.family == "hybrid":
+    if cfg.mixers:
+        params["blocks"] = tf.init_stack(cfg, ks[2])
+    elif cfg.enc_dec:
+        params["enc_blocks"] = tf.init_stack(cfg, ks[2],
+                                             ("attention",) * cfg.enc_layers)
+        params["blocks"] = tf.init_layers(
+            ks[3], cfg.num_layers, lambda k: tf.init_dec_block(cfg, k))
+        params["enc_final_norm"] = init_norm(cfg, ks[4])
+    else:
         def init_mamba_block(k):
             k1, k2 = jax.random.split(k)
             return {"ln1": init_norm(cfg, k1), "mamba": ssm_mod.init_mamba(cfg, k2)}
-        params["blocks"] = tf.init_stack(cfg, ks[2], cfg.num_layers, init_mamba_block)
+        params["blocks"] = tf.init_layers(ks[2], cfg.num_layers, init_mamba_block)
         sk = jax.random.split(ks[3], 4)
         params["shared_attn"] = {
             "ln1": init_norm(cfg, sk[0]), "attn": attn_mod.init_attn(cfg, sk[1]),
             "ln2": init_norm(cfg, sk[2]), "ffn": init_mlp(cfg, sk[3]),
         }
-    elif cfg.enc_dec:
-        params["enc_blocks"] = tf.init_stack(cfg, ks[2], cfg.enc_layers)
-        params["blocks"] = tf.init_stack(
-            cfg, ks[3], cfg.num_layers, lambda k: tf.init_dec_block(cfg, k))
-        params["enc_final_norm"] = init_norm(cfg, ks[4])
-    else:
-        params["blocks"] = tf.init_stack(cfg, ks[2], cfg.num_layers)
 
     if cfg.vision_tokens:
         params["vision_proj"] = {
@@ -100,7 +99,8 @@ def encode(cfg: ModelConfig, params, frames, *, impl: Impl):
     x = frames + sinusoid(Se, D).astype(frames.dtype)[None]
     positions = jnp.broadcast_to(jnp.arange(Se, dtype=jnp.int32)[None], (B, Se))
     x, _ = tf.apply_stack(cfg, params["enc_blocks"], x, positions=positions,
-                          impl=impl, causal=False, use_rope=False)
+                          impl=impl, kinds=("attention",) * cfg.enc_layers,
+                          causal=False, use_rope=False)
     return apply_norm(cfg, params["enc_final_norm"], x)
 
 
@@ -115,20 +115,17 @@ def forward(cfg: ModelConfig, params, batch, *, impl: Impl = Impl(),
 
     x = _embed_input(cfg, params, batch, dtype)
 
-    if cfg.enc_dec:
+    if cfg.mixers:
+        x, aux = tf.apply_stack(cfg, params["blocks"], x, positions=positions,
+                                impl=impl)
+    elif cfg.enc_dec:
         enc_out = encode(cfg, params, batch["frames"].astype(dtype), impl=impl)
         x = x + sinusoid(S, cfg.d_model).astype(dtype)[None]
         x, aux = tf.apply_dec_stack(cfg, params["blocks"], x, enc_out,
                                     positions=positions, impl=impl)
-    elif cfg.layer_types:
-        x, aux = tf.apply_pattern_stack(cfg, params["blocks"], x,
-                                        positions=positions, impl=impl)
-    elif cfg.family == "hybrid":
+    else:
         x, aux = tf.apply_hybrid_stack(cfg, params["blocks"], params["shared_attn"],
                                        x, positions=positions, impl=impl)
-    else:
-        x, aux = tf.apply_stack(cfg, params["blocks"], x, positions=positions,
-                                impl=impl)
 
     if last_only:
         x = x[:, -1:]
@@ -187,8 +184,8 @@ def init_decode_state(cfg: ModelConfig, params, batch: int, max_seq: int, *,
                       dtype=jnp.bfloat16, impl: Impl = Impl(),
                       enc_out: Optional[jnp.ndarray] = None):
     extra = {}
-    if cfg.layer_types:
-        n_attn = cfg.layer_types.count("attention")
+    if cfg.mixers:
+        n_attn = cfg.mixers.count("attention")
         caches = {}
         if cfg.num_layers - n_attn:
             caches["mamba"] = kvcache.stack_caches(
@@ -196,23 +193,13 @@ def init_decode_state(cfg: ModelConfig, params, batch: int, max_seq: int, *,
         if n_attn:
             caches["attn"] = kvcache.stack_caches(
                 [_attn_cache_spec(cfg, batch, max_seq, dtype)] * n_attn)
-        # routed (token, expert) choices a layer: one column per held
-        # expert, the last for the experts held elsewhere; counted over
-        # the rows that ``occupied`` marks
-        extra = {"expert_load": jnp.zeros(
-                     (cfg.num_layers, cfg.moe.held + 1), jnp.int32),
-                 "occupied": jnp.ones((batch,), jnp.int32)}
-    elif cfg.family == "ssm":
-        caches = kvcache.stack_caches(
-            [_ssm_state_spec(cfg, batch, dtype)] * cfg.num_layers)
-    elif cfg.family == "hybrid":
-        n_seg = cfg.num_layers // cfg.attn_every
-        attn_one = _attn_cache_spec(cfg, batch, max_seq, dtype)
-        caches = {
-            "mamba": kvcache.stack_caches(
-                [_ssm_state_spec(cfg, batch, dtype)] * cfg.num_layers),
-            "attn": kvcache.stack_caches([attn_one] * n_seg),
-        }
+        if cfg.ffn_kind == "held":
+            # routed (token, expert) choices a layer: one column per held
+            # expert, the last for the experts held elsewhere; counted
+            # over the rows that ``occupied`` marks
+            extra = {"expert_load": jnp.zeros(
+                         (cfg.num_layers, cfg.moe.held + 1), jnp.int32),
+                     "occupied": jnp.ones((batch,), jnp.int32)}
     elif cfg.enc_dec:
         assert enc_out is not None, "enc-dec decode state needs encoder output"
         self_one = kvcache.init_dense_cache(batch, max_seq, cfg.kv_heads_eff,
@@ -231,8 +218,13 @@ def init_decode_state(cfg: ModelConfig, params, batch: int, max_seq: int, *,
             "cross": cross,
         }
     else:
-        one = _attn_cache_spec(cfg, batch, max_seq, dtype)
-        caches = kvcache.stack_caches([one] * cfg.num_layers)
+        n_seg = cfg.num_layers // cfg.attn_every
+        attn_one = _attn_cache_spec(cfg, batch, max_seq, dtype)
+        caches = {
+            "mamba": kvcache.stack_caches(
+                [_ssm_state_spec(cfg, batch, dtype)] * cfg.num_layers),
+            "attn": kvcache.stack_caches([attn_one] * n_seg),
+        }
     return {"caches": caches, "pos": jnp.int32(0), **extra}
 
 
@@ -243,7 +235,14 @@ def decode_step(cfg: ModelConfig, params, state, token, *, impl: Impl = Impl(),
     x = _embed(cfg, params, token, dtype)
     state = dict(state)
 
-    if cfg.enc_dec:
+    if cfg.mixers:
+        x, new_caches, load = tf.decode_stack(
+            cfg, params["blocks"], state["caches"], x, pos, impl=impl)
+        if load is not None:
+            occupied = state["occupied"].astype(load.dtype)[None, :, None, None]
+            state["expert_load"] = state["expert_load"] + jnp.sum(
+                load * occupied, axis=(1, 2)).astype(jnp.int32)
+    elif cfg.enc_dec:
         half = cfg.d_model // 2
         freq = jnp.exp(-math.log(10000.0)
                        * jnp.arange(half, dtype=jnp.float32) / half)
@@ -256,20 +255,10 @@ def decode_step(cfg: ModelConfig, params, state, token, *, impl: Impl = Impl(),
             cfg, params["blocks"],
             {"self": caches["self"], "cross": caches["cross"]}, x, pos, impl=impl)
         new_caches = {"self": new_caches["self"], "cross": caches["cross"]}
-    elif cfg.layer_types:
-        x, new_caches, load = tf.decode_pattern_stack(
-            cfg, params["blocks"], state["caches"], x, pos, impl=impl)
-        occupied = state["occupied"].astype(load.dtype)[None, :, None, None]
-        state["expert_load"] = state["expert_load"] + jnp.sum(
-            load * occupied, axis=(1, 2)).astype(jnp.int32)
-    elif cfg.family == "hybrid":
+    else:
         x, new_caches = tf.decode_hybrid_stack(cfg, params["blocks"],
                                                params["shared_attn"],
                                                state["caches"], x, pos, impl=impl)
-    else:
-        x, new_caches = tf.decode_stack(cfg, params["blocks"], state["caches"],
-                                        x, pos, impl=impl,
-                                        use_rope=not cfg.enc_dec)
 
     x = apply_norm(cfg, params["final_norm"], x)
     logits = lm_logits(cfg, params["embed"], x)

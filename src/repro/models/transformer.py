@@ -1,20 +1,22 @@
-"""Block assembly and scan-over-layers stacks for every family.
+"""Scan-over-layers stacks: the layer stack of every decoder-only family,
+zamba2's shared-block hybrid, and whisper's encoder-decoder.
 
 Layer parameters are stacked on a leading L axis (init via vmap over keys)
-and consumed with lax.scan — the HLO contains ONE block body regardless of
-depth, which keeps XLA compile time flat across the 4..64-layer archs and is
-what makes the 512-device dry-run tractable. Activation rematerialization
-wraps the scan body (``remat="block"`` saves only block boundaries).
+and consumed with lax.scan — the HLO contains ONE layer body per run of
+layers regardless of depth, which keeps XLA compile time flat across the
+4..64-layer archs and is what makes the 512-device dry-run tractable.
+Activation rematerialization wraps the scan body (``remat="block"`` saves
+only block boundaries).
 
-Families:
-  dense/vlm : [attn → ffn] × L
-  moe       : [attn → moe-ffn] × L (+ aux losses accumulated through the scan)
-  ssm       : [mamba2] × L
-  hybrid    : segments of ``attn_every`` mamba blocks with a SHARED attention
-              block applied between segments (zamba2); or, with
-              ``layer_types``, one mixer a layer (mamba or attention) and the
-              dropless held-expert FFN after every mixer (granite-4.0-h)
-  audio     : encoder [attn → ffn] × Le, decoder [self → cross → ffn] × Ld
+Stacks:
+  layer stack  : each layer a mixer (``cfg.mixers``: mamba or attention)
+                 and then a feed-forward (``cfg.ffn_kind``): dense/vlm
+                 [attn → mlp], moe [attn → capacity moe, aux losses
+                 accumulated through the scan], ssm [mamba], granite-4.0-h
+                 [mamba or attn → held-share experts]; whisper's encoder
+  shared block : zamba2 — segments of ``attn_every`` mamba blocks with a
+                 SHARED attention block applied between segments
+  enc-dec      : whisper's decoder [self → cross → ffn] × Ld
 """
 from __future__ import annotations
 
@@ -69,71 +71,175 @@ def _add_aux(a, b):
 
 
 # ---------------------------------------------------------------------------
-# single blocks
+# the layer stack: a mixer and then a feed-forward in every layer
 # ---------------------------------------------------------------------------
+#
+#   h  = x + r * Mixer_l(norm1_l(x))          Mixer_l: mamba or attention
+#   x' = h + r * FFN_l(norm2_l(h))            r: residual_multiplier
+#
+# Parameters: ``ln1`` (and ``ln2``, ``ffn`` where the layers have a
+# feed-forward) stacked over all L layers, ``mamba`` and ``attn`` each over
+# the layers of its kind. Each run of consecutive layers of one kind is one
+# scan; a homogeneous model is one run.
 
-def init_block(cfg: ModelConfig, key):
-    ks = jax.random.split(key, 4)
-    if cfg.family == "ssm":
-        return {"ln1": init_norm(cfg, ks[0]), "mamba": ssm_mod.init_mamba(cfg, ks[1])}
-    p = {"ln1": init_norm(cfg, ks[0]), "attn": attn_mod.init_attn(cfg, ks[1]),
-         "ln2": init_norm(cfg, ks[2])}
-    if cfg.moe:
-        p["ffn"] = moe_mod.init_moe(cfg, ks[3])
+def init_layers(key, n_layers: int, init_one):
+    """``n_layers`` draws of ``init_one(key)`` stacked on a leading axis."""
+    return jax.vmap(init_one)(jax.random.split(key, n_layers))
+
+
+def layer_runs(kinds):
+    """→ [(kind, first layer, first index among that kind's layers, count)]
+    for each run of consecutive layers of one kind."""
+    runs, seen = [], {"mamba": 0, "attention": 0}
+    for i, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            k, l0, j0, n = runs[-1]
+            runs[-1] = (k, l0, j0, n + 1)
+        else:
+            runs.append((kind, i, seen[kind], 1))
+        seen[kind] += 1
+    return runs
+
+
+_MIXER = {"mamba": "mamba", "attention": "attn"}
+_INIT_MIXER = {"mamba": ssm_mod.init_mamba, "attention": attn_mod.init_attn}
+_INIT_FFN = {"held": moe_mod.init_held_experts, "moe": moe_mod.init_moe,
+             "mlp": init_mlp}
+
+
+def init_stack(cfg: ModelConfig, key, kinds=None):
+    """The stack of layers whose mixers are ``kinds`` (default
+    ``cfg.mixers``), each followed by a ``cfg.ffn_kind`` feed-forward."""
+    kinds = cfg.mixers if kinds is None else kinds
+    L = len(kinds)
+    ks = jax.random.split(key, 5)
+    blocks = {"ln1": init_layers(ks[0], L, lambda k: init_norm(cfg, k))}
+    if cfg.ffn_kind:
+        init_ffn = _INIT_FFN[cfg.ffn_kind]
+        blocks["ln2"] = init_layers(ks[1], L, lambda k: init_norm(cfg, k))
+        blocks["ffn"] = init_layers(ks[2], L, lambda k: init_ffn(cfg, k))
+    for (kind, name), k in zip(_MIXER.items(), ks[3:]):
+        if kind in kinds:
+            blocks[name] = init_layers(
+                k, kinds.count(kind),
+                lambda kk, kind=kind: _INIT_MIXER[kind](cfg, kk))
+    return blocks
+
+
+def _at(tree, i):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _per_layer(blocks):
+    """The stacks over all L layers (``ln1``, ``ln2``, ``ffn``)."""
+    return {k: v for k, v in blocks.items() if k not in ("mamba", "attn")}
+
+
+def _residual(cfg: ModelConfig, x, y):
+    # r == 1 adds no multiply to the step
+    r = cfg.residual_multiplier
+    return x + y if r == 1.0 else x + r * y
+
+
+def _ffn(cfg: ModelConfig, w, h):
+    """The layer's feed-forward after its mixer; ``w`` holds the layer's
+    ``ln2`` and ``ffn`` → (h', what it reports: the held experts' load
+    (..., held + 1), the capacity layer's aux losses, or None)."""
+    if not cfg.ffn_kind:
+        return h, None
+    u = apply_norm(cfg, w["ln2"], h)
+    if cfg.ffn_kind == "held":
+        y, out = moe_mod.apply_held_experts(cfg, w["ffn"], u)
+    elif cfg.ffn_kind == "moe":
+        y, out = moe_mod.apply_moe(cfg, w["ffn"], u)
     else:
-        p["ffn"] = init_mlp(cfg, ks[3])
-    return p
+        y, out = apply_mlp(cfg, w["ffn"], u), None
+    return _residual(cfg, h, y), out
 
 
-def apply_block(cfg: ModelConfig, p, x, *, positions, impl: Impl,
-                causal=True, use_rope=True):
-    """Full-sequence block. Returns (x, aux)."""
+def _slice(tree, i0, n):
+    return jax.tree.map(lambda a: a[i0:i0 + n], tree)
+
+
+def apply_stack(cfg: ModelConfig, blocks, x, *, positions, impl: Impl,
+                kinds=None, causal=True, use_rope=True):
+    """Full-sequence layer stack (train / prefill) → (x, aux). Each run
+    scans its slice of the stacks as the scan's input (a one-run model's
+    slice is the whole stack): read by index from the closed-over stack,
+    the backward pass would carry a cotangent the size of the stack."""
+    kinds = cfg.mixers if kinds is None else kinds
     aux = zero_aux(cfg)
-    if cfg.family == "ssm":
-        x = x + ssm_mod.apply_mamba(cfg, p["mamba"], apply_norm(cfg, p["ln1"], x),
-                                    impl=impl.ssd)
-        return x, aux
-    h = attn_mod.apply_attn(cfg, p["attn"], apply_norm(cfg, p["ln1"], x),
-                            positions=positions, causal=causal, use_rope=use_rope,
-                            impl=impl.attention, q_chunk=impl.q_chunk,
-                            kv_chunk=impl.kv_chunk)
-    x = x + h
-    h = apply_norm(cfg, p["ln2"], x)
-    if cfg.moe:
-        h, aux = moe_mod.apply_moe(cfg, p["ffn"], h)
-    else:
-        h = apply_mlp(cfg, p["ffn"], h)
-    return x + h, aux
+    for kind, l0, j0, n in layer_runs(kinds):
+        def body(carry, w, kind=kind):
+            h, aux = carry
+            lw, p = w
+            h = impl.anchor(h)
+            u = apply_norm(cfg, lw["ln1"], h)
+            with jax.named_scope(kind):
+                if kind == "mamba":
+                    y = ssm_mod.apply_mamba(cfg, p, u, impl=impl.ssd)
+                else:
+                    y = attn_mod.apply_attn(
+                        cfg, p, u, positions=positions, causal=causal,
+                        use_rope=use_rope, impl=impl.attention,
+                        q_chunk=impl.q_chunk, kv_chunk=impl.kv_chunk)
+            h, out = _ffn(cfg, lw, _residual(cfg, h, y))
+            if cfg.ffn_kind == "moe":
+                aux = _add_aux(aux, out)
+            return (h, aux), None
 
-
-# ---------------------------------------------------------------------------
-# stacked init
-# ---------------------------------------------------------------------------
-
-def init_stack(cfg: ModelConfig, key, n_layers: int, init_one=None):
-    init_one = init_one or (lambda k: init_block(cfg, k))
-    keys = jax.random.split(key, n_layers)
-    return jax.vmap(init_one)(keys)
-
-
-# ---------------------------------------------------------------------------
-# forward stacks (train / prefill without cache)
-# ---------------------------------------------------------------------------
-
-def apply_stack(cfg: ModelConfig, stacked, x, *, positions, impl: Impl,
-                causal=True, use_rope=True):
-    def body(carry, layer_p):
-        h, aux = carry
-        h = impl.anchor(h)
-        h, aux_l = apply_block(cfg, layer_p, h, positions=positions, impl=impl,
-                               causal=causal, use_rope=use_rope)
-        return (h, _add_aux(aux, aux_l)), None
-
-    if impl.remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux), _ = jax.lax.scan(body, (impl.anchor(x), zero_aux(cfg)), stacked)
+        if impl.remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        xs = (_slice(_per_layer(blocks), l0, n),
+              _slice(blocks[_MIXER[kind]], j0, n))
+        (x, aux), _ = jax.lax.scan(body, (impl.anchor(x), aux), xs)
     return x, aux
 
+
+def decode_stack(cfg: ModelConfig, blocks, caches, x, pos, *, impl: Impl):
+    """One token through the layer stack. caches = {"mamba": SSM states
+    stacked over the mamba layers, "attn": KV caches stacked over the
+    attention layers}, carried through each run's scan: a layer reads and
+    writes its own by index (a KV cache takes only its new rows), so with
+    the state donated every write is in place and no layer of a cache is
+    copied. → (x, new caches, load (L, B, 1, held + 1): each layer's
+    routed choices per token, where the feed-forward is held experts, else
+    None)."""
+    caches, loads = dict(caches), []
+    per_layer = _per_layer(blocks)
+    for kind, l0, j0, n in layer_runs(cfg.mixers):
+        name = _MIXER[kind]
+
+        def body(carry, idx, kind=kind, name=name):
+            h, c = carry
+            i, j = idx
+            lw = _at(per_layer, i)
+            u = apply_norm(cfg, lw["ln1"], h)
+            p = _at(blocks[name], j)
+            with jax.named_scope(kind):
+                if kind == "mamba":
+                    y, cj = ssm_mod.decode_mamba(
+                        cfg, p, u, kvcache.cache_layer(c, j))
+                    c = kvcache.cache_put_layer(c, cj, j)
+                else:
+                    y, c = attn_mod.decode_attn(
+                        cfg, p, u, c, pos, impl=impl.decode_attention,
+                        kv_chunk=impl.kv_chunk, layer=j)
+            h, out = _ffn(cfg, lw, _residual(cfg, h, y))
+            return (h, c), out if cfg.ffn_kind == "held" else None
+
+        (x, caches[name]), load = jax.lax.scan(
+            body, (x, caches[name]),
+            (jnp.arange(l0, l0 + n), jnp.arange(j0, j0 + n)))
+        loads.append(load)
+    load = jnp.concatenate(loads) if cfg.ffn_kind == "held" else None
+    return x, caches, load
+
+
+# ---------------------------------------------------------------------------
+# shared-block hybrid (zamba2)
+# ---------------------------------------------------------------------------
 
 def apply_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, x, *,
                        positions, impl: Impl):
@@ -173,51 +279,6 @@ def apply_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, x, *,
     return x, zero_aux(cfg)
 
 
-# ---------------------------------------------------------------------------
-# decode blocks (single new token through a cached stack)
-# ---------------------------------------------------------------------------
-
-def decode_block(cfg: ModelConfig, p, x, cache, pos, *, impl: Impl,
-                 use_rope=True, layer=None):
-    """Returns (x, new_cache). ``layer`` given: ``cache`` is the whole
-    stack, read and written at that layer, and the stack is returned."""
-    if cfg.family == "ssm":
-        h, new_state = ssm_mod.decode_mamba(
-            cfg, p["mamba"], apply_norm(cfg, p["ln1"], x),
-            kvcache.cache_layer(cache, layer))
-        if layer is not None:
-            new_state = kvcache.cache_put_layer(cache, new_state, layer)
-        return x + h, new_state
-    h, new_cache = attn_mod.decode_attn(cfg, p["attn"], apply_norm(cfg, p["ln1"], x),
-                                        cache, pos, use_rope=use_rope,
-                                        impl=impl.decode_attention,
-                                        kv_chunk=impl.kv_chunk, layer=layer)
-    x = x + h
-    h = apply_norm(cfg, p["ln2"], x)
-    if cfg.moe:
-        h, _ = moe_mod.apply_moe(cfg, p["ffn"], h)
-    else:
-        h = apply_mlp(cfg, p["ffn"], h)
-    return x + h, new_cache
-
-
-def decode_stack(cfg: ModelConfig, stacked, caches, x, pos, *, impl: Impl,
-                 use_rope=True):
-    """Scan the layer stack carrying the token activation and the stacked
-    caches: layer l reads and writes its own cache by index (a KV cache
-    takes only its new rows), so with the state donated every write is in
-    place and no layer of the cache is copied."""
-    def body(carry, inp):
-        h, c = carry
-        layer_p, l = inp
-        return decode_block(cfg, layer_p, h, c, pos, impl=impl,
-                            use_rope=use_rope, layer=l), None
-
-    layers = jnp.arange(jax.tree.leaves(stacked)[0].shape[0])
-    (x, caches), _ = jax.lax.scan(body, (x, caches), (stacked, layers))
-    return x, caches
-
-
 def decode_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, caches,
                         x, pos, *, impl: Impl):
     """caches = {"mamba": stacked ssm states (L,...), "attn": stacked dense/ring
@@ -254,128 +315,6 @@ def decode_hybrid_stack(cfg: ModelConfig, mamba_stack, shared_block, caches,
         "attn": new_attn,
     }
     return x, new_caches
-
-
-# ---------------------------------------------------------------------------
-# pattern hybrid (layer_types): mixer + held-expert FFN in every layer
-# ---------------------------------------------------------------------------
-#
-#   h  = x + r * Mixer_l(norm1_l(x))          Mixer_l: mamba or attention
-#   x' = h + r * (MoE_l + Shared_l)(norm2_l(h))          r: residual_multiplier
-#
-# Parameters: ``ln1``, ``ln2`` and ``ffn`` stacked over all L layers,
-# ``mamba`` and ``attn`` each over the layers of its kind. Each run of
-# consecutive layers of one kind is one scan whose body reads its layer's
-# weights (and its cache) by index: no slice of a stack is copied.
-
-def layer_runs(layer_types):
-    """→ [(kind, first layer, first index among that kind's layers, count)]
-    for each run of consecutive layers of one kind."""
-    runs, seen = [], {"mamba": 0, "attention": 0}
-    for i, kind in enumerate(layer_types):
-        if runs and runs[-1][0] == kind:
-            k, l0, j0, n = runs[-1]
-            runs[-1] = (k, l0, j0, n + 1)
-        else:
-            runs.append((kind, i, seen[kind], 1))
-        seen[kind] += 1
-    return runs
-
-
-_MIXER = {"mamba": "mamba", "attention": "attn"}
-
-
-def init_pattern_stack(cfg: ModelConfig, key):
-    L = cfg.num_layers
-    n_attn = cfg.layer_types.count("attention")
-    ks = jax.random.split(key, 5)
-    blocks = {
-        "ln1": init_stack(cfg, ks[0], L, lambda k: init_norm(cfg, k)),
-        "ln2": init_stack(cfg, ks[1], L, lambda k: init_norm(cfg, k)),
-        "ffn": init_stack(cfg, ks[2], L,
-                          lambda k: moe_mod.init_held_experts(cfg, k)),
-    }
-    if L - n_attn:
-        blocks["mamba"] = init_stack(cfg, ks[3], L - n_attn,
-                                     lambda k: ssm_mod.init_mamba(cfg, k))
-    if n_attn:
-        blocks["attn"] = init_stack(cfg, ks[4], n_attn,
-                                    lambda k: attn_mod.init_attn(cfg, k))
-    return blocks
-
-
-def _at(tree, i):
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
-
-
-def _pattern_ffn(cfg: ModelConfig, blocks, i, h):
-    """The layer's feed-forward on h → (h', load (..., held + 1))."""
-    u = apply_norm(cfg, _at(blocks["ln2"], i), h)
-    y, load = moe_mod.apply_held_experts(cfg, _at(blocks["ffn"], i), u)
-    return h + cfg.residual_multiplier * y, load
-
-
-def _run_index(l0, j0, n):
-    return (jnp.arange(l0, l0 + n), jnp.arange(j0, j0 + n))
-
-
-def apply_pattern_stack(cfg: ModelConfig, blocks, x, *, positions, impl: Impl):
-    """Full-sequence pattern hybrid (train / prefill)."""
-    for kind, l0, j0, n in layer_runs(cfg.layer_types):
-        def body(h, idx, kind=kind):
-            i, j = idx
-            h = impl.anchor(h)
-            u = apply_norm(cfg, _at(blocks["ln1"], i), h)
-            p = _at(blocks[_MIXER[kind]], j)
-            with jax.named_scope(kind):
-                if kind == "mamba":
-                    y = ssm_mod.apply_mamba(cfg, p, u, impl=impl.ssd)
-                else:
-                    y = attn_mod.apply_attn(
-                        cfg, p, u, positions=positions, causal=True,
-                        impl=impl.attention, q_chunk=impl.q_chunk,
-                        kv_chunk=impl.kv_chunk)
-            h, _ = _pattern_ffn(cfg, blocks, i, h + cfg.residual_multiplier * y)
-            return h, None
-
-        if impl.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        x, _ = jax.lax.scan(body, impl.anchor(x), _run_index(l0, j0, n))
-    return x, zero_aux(cfg)
-
-
-def decode_pattern_stack(cfg: ModelConfig, blocks, caches, x, pos, *,
-                         impl: Impl):
-    """caches = {"mamba": SSM states stacked over the mamba layers, "attn":
-    KV caches stacked over the attention layers} → (x, new caches, load
-    (L, B, 1, held + 1)): each layer's routed choices per token."""
-    caches, loads = dict(caches), []
-    for kind, l0, j0, n in layer_runs(cfg.layer_types):
-        name = _MIXER[kind]
-
-        def body(carry, idx, kind=kind, name=name):
-            h, c = carry
-            i, j = idx
-            u = apply_norm(cfg, _at(blocks["ln1"], i), h)
-            p = _at(blocks[name], j)
-            with jax.named_scope(kind):
-                if kind == "mamba":
-                    y, cj = ssm_mod.decode_mamba(
-                        cfg, p, u, kvcache.cache_layer(c, j))
-                    c = kvcache.cache_put_layer(c, cj, j)
-                else:
-                    y, c = attn_mod.decode_attn(
-                        cfg, p, u, c, pos, impl=impl.decode_attention,
-                        kv_chunk=impl.kv_chunk, layer=j)
-            h, load = _pattern_ffn(cfg, blocks, i,
-                                   h + cfg.residual_multiplier * y)
-            return (h, c), load
-
-        (x, caches[name]), load = jax.lax.scan(
-            body, (x, caches[name]), _run_index(l0, j0, n))
-        loads.append(load)
-    return x, caches, jnp.concatenate(loads)
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.domains import DomainKey
 from repro.core.fabric import FabricChannel, MPKLinkFabric, neighbor_exchange
-from repro.models.transformer import Impl, apply_block
+from repro.models.transformer import Impl, apply_stack
 from repro.utils import match_vma
 
 
@@ -74,12 +74,12 @@ def pipeline_apply(cfg: ModelConfig, local_params, x_micro, *,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (mb, S))
     T = n_micro + n - 1
 
+    # a stage holds L/n layers of a one-kind stack
+    kinds = cfg.mixers[:jax.tree.leaves(params)[0].shape[0]]
+
     def run_stage(h):
-        def layer(hh, lp):
-            out, _ = apply_block(cfg, lp, hh, positions=positions, impl=impl)
-            return out, None
-        h, _ = jax.lax.scan(layer, h, params)
-        return h
+        return apply_stack(cfg, params, h, positions=positions, impl=impl,
+                           kinds=kinds)[0]
 
     def tick(carry, t):
         held, ok = carry

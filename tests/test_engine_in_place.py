@@ -2,7 +2,8 @@
 donated, the caches are carried through the layer loop, and a dense cache
 takes only its new rows. Checked on the compiled step (aliasing, no copy of
 a layer or of the stack) and on the tokens served, against the plain walk
-that scans the caches as inputs and emits new ones. Tiny models on the CPU."""
+that runs each layer on its own one-layer cache, scanned as an input. Tiny
+models on the CPU."""
 import re
 
 import jax
@@ -11,7 +12,10 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.models import init_params
+from repro.models import attention as attn_mod
+from repro.models import ssm as ssm_mod
 from repro.models import transformer as tf
+from repro.models.layers import apply_mlp, apply_norm
 from repro.models.transformer import Impl
 from repro.runtime import Request, ServingEngine
 
@@ -32,7 +36,7 @@ def model(request):
 
 @pytest.fixture(scope="module", params=["olmo-1b", "mamba2-1.3b"])
 def stacked_model(request):
-    """A model whose layers are one stack (dense, SSM)."""
+    """A model whose layers are all of one kind (dense, SSM)."""
     return _model(request.param)
 
 
@@ -99,7 +103,7 @@ def test_step_aliases_its_state_and_copies_no_layer(model):
     copies = [t for _, _, t, op in ins if op in ("copy", "copy-start")]
     assert not stacks & set(copies), copies
     if cfg.family == "dense":
-        k = eng.state["caches"]["k"]
+        k = eng.state["caches"]["attn"]["k"]
         whole = {_shape(k), _shape(k, (1,) + k.shape[1:])}
         # no buffer holds a layer of the cache, and nothing writes one:
         # the layer is read inside the reductions, the rows scattered
@@ -109,14 +113,26 @@ def test_step_aliases_its_state_and_copies_no_layer(model):
                 assert t not in whole, (t, op)
 
 
-def _old_decode_stack(cfg, stacked, caches, x, pos, *, impl, use_rope=True):
-    """The plain walk: the caches scanned as inputs, new ones emitted."""
-    def body(h, inp):
-        layer_p, cache_l = inp
-        return tf.decode_block(cfg, layer_p, h, cache_l, pos, impl=impl,
-                               use_rope=use_rope)
+def _plain_decode_stack(cfg, blocks, caches, x, pos, *, impl):
+    """The plain walk of a one-kind stack: each layer's weights and its own
+    one-layer cache scanned as inputs, its new cache emitted."""
+    name = "mamba" if "mamba" in blocks else "attn"
 
-    return jax.lax.scan(body, x, (stacked, caches))
+    def body(h, inp):
+        w, cache = inp
+        u = apply_norm(cfg, w["ln1"], h)
+        if name == "mamba":
+            y, cache = ssm_mod.decode_mamba(cfg, w["mamba"], u, cache)
+            return h + y, cache
+        y, cache = attn_mod.decode_attn(cfg, w["attn"], u, cache, pos,
+                                        impl=impl.decode_attention,
+                                        kv_chunk=impl.kv_chunk)
+        h = h + y
+        return h + apply_mlp(cfg, w["ffn"], apply_norm(cfg, w["ln2"], h)), \
+            cache
+
+    x, new = jax.lax.scan(body, x, (blocks, caches[name]))
+    return x, {name: new}, None
 
 
 def _requests(vocab):
@@ -155,7 +171,7 @@ def test_in_place_serves_the_plain_walks_tokens(stacked_model, greedy,
     cfg, _ = stacked_model
     kw = dict(greedy=greedy, seed=2147483713, max_batch=3)
     got = _serve(_engine(stacked_model, **kw), cfg.vocab_size)
-    monkeypatch.setattr(tf, "decode_stack", _old_decode_stack)
+    monkeypatch.setattr(tf, "decode_stack", _plain_decode_stack)
     want = _serve(_engine(stacked_model, **kw), cfg.vocab_size)
     assert got[0] == want[0]
     assert got[1] == want[1] and len(got[1]) == len(_requests(2))

@@ -67,7 +67,8 @@ def test_decode_runs(arch):
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "mamba2-1.3b", "zamba2-2.7b",
                                   "whisper-tiny", "mixtral-8x7b",
                                   "llava-next-mistral-7b",
-                                  "granite-4.0-h-small"])
+                                  "granite-4.0-h-small", "olmo-1b",
+                                  "qwen3-14b"])
 def test_decode_matches_forward(arch):
     cfg = get_reduced(arch)
     if cfg.moe:
@@ -98,6 +99,53 @@ def test_decode_matches_forward(arch):
         outs.append(lg[:, 0])
     dec_logits = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(dec_logits, ref_logits, rtol=2e-4, atol=2e-4)
+
+
+DECODER_ONLY = [a for a in ARCH_IDS
+                if get_reduced(a).family in ("dense", "moe", "ssm", "vlm")
+                or get_reduced(a).layer_types]
+
+
+def _stacks(cfg):
+    """The stacks of ``params["blocks"]`` a family has → their layer count."""
+    L = cfg.num_layers
+    if cfg.family == "ssm":
+        return {"ln1": L, "mamba": L}
+    stacks = {"ln1": L, "ln2": L, "ffn": L}
+    if cfg.layer_types:
+        n_attn = cfg.layer_types.count("attention")
+        return dict(stacks, mamba=L - n_attn, attn=n_attn)
+    return dict(stacks, attn=L)
+
+
+@pytest.mark.parametrize("arch", DECODER_ONLY)
+def test_layer_stack_keeps_each_familys_tree(arch):
+    """Every decoder-only family's layers are one stack: a mixer's weights
+    stacked over the layers of its kind, the norms and the feed-forward
+    over all layers; an SSM has no feed-forward. The benchmark's reference
+    weights are laid out so."""
+    cfg = get_reduced(arch)
+    blocks = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))["blocks"]
+    want = _stacks(cfg)
+    assert set(blocks) == set(want)
+    for name, n in want.items():
+        for leaf in jax.tree.leaves(blocks[name]):
+            assert leaf.shape[0] == n, (name, leaf.shape)
+    if cfg.ffn_kind:
+        ffn = {"held": {"router", "gate", "up", "down", "shared"},
+               "moe": {"router", "gate", "up", "down"},
+               "mlp": {"up", "down", "gate"} if cfg.mlp_type == "glu"
+               else {"up", "down"}}[cfg.ffn_kind]
+        assert set(blocks["ffn"]) == ffn
+
+
+@pytest.mark.parametrize("arch", DECODER_ONLY)
+def test_param_count_is_what_init_makes(arch):
+    cfg = get_reduced(arch)
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert cfg.param_count() == sum(x.size for x in jax.tree.leaves(params))
 
 
 def test_vlm_vision_prefix_changes_output():

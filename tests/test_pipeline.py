@@ -21,7 +21,7 @@ from repro.runtime.pipeline import pipeline_apply, stage_split
 cfg = replace(get_reduced("llama3.2-1b"), num_layers=8)
 impl = Impl(attention="naive", remat=False)
 key0 = jax.random.PRNGKey(0)
-stacked = tf.init_stack(cfg, key0, cfg.num_layers)
+stacked = tf.init_stack(cfg, key0)
 
 n_micro, mb, S = 4, 2, 16
 x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, S, cfg.d_model))

@@ -232,5 +232,5 @@ def test_decode_step_updates_its_state_in_place(chip, arch):
             dest = line.split(" = ", 1)[-1].lstrip("(").split("}", 1)[0]
             assert "S(" in dest or not any(t in dest for t in stacks), line
     if cfg.family == "dense":
-        k = state["caches"]["k"]
+        k = state["caches"]["attn"]["k"]
         assert m.temp_size_in_bytes < k.size // k.shape[0] * 4, m
