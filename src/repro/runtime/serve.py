@@ -9,6 +9,15 @@ high-throughput decode. Prompt tokens are fed incrementally through the
 same decode path (teacher-forced), then generation continues from the
 model's samples until EOS/max_new.
 
+The tick keeps one step in flight: it dispatches step t+1 before it reads
+step t's tokens. A jitted sampling program (``engine_sample``) picks each
+generating slot's next input from step t's logits on the device, so the
+host uploads only prompt tokens and reads one int32 array a tick, while
+step t+1 runs. Retirement by ``max_new`` or ``max_seq`` is known when the
+step is dispatched: the slot is freed right after it, and its request
+retires when the next tick reads the last token. An EOS is seen one step
+late: the token the slot made in the step after it is discarded.
+
 Serves every configuration whose caches take per-slot positions: dense
 KV caches, SSM states, and both side by side in the hybrids (ring caches
 need uniform positions and are served by the batch path / dry-run cells).
@@ -21,7 +30,7 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +84,6 @@ class ServingEngine:
         self.cfg, self.params = cfg, jax.device_put(params, device)
         self.B, self.max_seq = max_batch, max_seq
         self.impl, self.dtype = impl, dtype
-        self.greedy = greedy
         self.key = jax.device_put(jax.random.PRNGKey(seed), device)
 
         self.state = self._fresh_state()
@@ -86,18 +94,42 @@ class ServingEngine:
         # the caller's state is gone once the step is dispatched
         self._step = jax.jit(engine_decode_step, donate_argnums=(1,))
 
+        def engine_sample(logits, feed, key):   # its module: jit_engine_sample
+            """→ the next step's tokens (the host's feed where it gives
+            one, ≥ 0, else the slot's own sample), the samples, the key."""
+            if greedy:
+                drawn = jnp.argmax(logits[:, -1], -1)
+            else:
+                key, k = jax.random.split(key)
+                drawn = jax.random.categorical(k, logits[:, -1])
+            drawn = drawn.astype(jnp.int32)[:, None]
+            return jnp.where(feed >= 0, feed, drawn), drawn, key
+        self._sample = jax.jit(engine_sample)
+
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.queue: List[Request] = []
-        self.current_token = np.zeros((max_batch, 1), np.int32)
+        # prompt tokens fed by dispatched steps, and all tokens fed (the
+        # slot's position in ``state["pos"]``)
         self.prompt_cursor = np.zeros(max_batch, np.int64)
+        self._pos = np.zeros(max_batch, np.int64)
         self.completed: List[Request] = []
-        # written by the tick alone: ticks that ran the step; occupied
-        # slots a tick that fed a prompt token, and those that generated
-        # one; blocking device-to-host reads (the sampled tokens)
+        # the logits of the step in flight; for each dispatched step whose
+        # tokens the host has not read, oldest first, (slot, request, last)
+        # for each token it makes: kept until its tokens are taken in, so
+        # a tick that dies before then still knows who waits for them
+        self._logits = None
+        self._unread: List[List[Tuple[int, Request, bool]]] = []
+        # written by the tick alone: ticks that ran the step, and those
+        # that ran it with the previous step's tokens still unread;
+        # occupied slots a step that fed a prompt token and made none, and
+        # those that made one; blocking device-to-host reads (the sampled
+        # tokens); tokens made after an EOS, thrown away
         self.ticks = 0
+        self.overlapped_ticks = 0
         self.prompt_slot_ticks = 0
         self.decode_slot_ticks = 0
         self.host_syncs = 0
+        self.discarded_slot_steps = 0
         # span names, tagged with the chip whose idle time they explain
         (self._sp_tick, self._sp_admit, self._sp_slot_reset,
          self._sp_dispatch, self._sp_sample, self._sp_bookkeep) = (
@@ -123,7 +155,8 @@ class ServingEngine:
         step that failed after dispatch, or a caller of ``_step`` that kept
         its input in place of the output. Its buffers are gone; the engine
         starts again from an empty state, and any slot still in flight
-        continues without what the lost state held."""
+        continues without what the lost state held. The logits of the step
+        in flight are its output, not its state, and are still read."""
         return any(a.is_deleted() for a in jax.tree.leaves(self.state))
 
     # -- request management -----------------------------------------------
@@ -153,20 +186,29 @@ class ServingEngine:
                         self.state["caches"])
                     self.state["pos"] = self.state["pos"].at[b].set(0)
                     self._mark_occupied(b, 1)
-                self.current_token[b, 0] = req.prompt[0]
-                self.prompt_cursor[b] = 1
+                self.prompt_cursor[b] = 0
+                self._pos[b] = 0
 
-    def _retire(self, b: int):
-        req = self.slots[b]
-        req.done = True
-        req.finished_at = time.perf_counter()
-        self.completed.append(req)
+    def _release(self, b: int):
+        """Free slot ``b`` for admission; its request may still wait for
+        its last token."""
         self.slots[b] = None
         self._mark_occupied(b, 0)
 
+    def _retire(self, req: Request):
+        req.done = True
+        req.finished_at = time.perf_counter()
+        self.completed.append(req)
+        if self.slots[req.slot] is req:
+            self._release(req.slot)
+
     def _mark_occupied(self, b: int, flag: int):
-        if "occupied" in self.state:
-            self.state["occupied"] = self.state["occupied"].at[b].set(flag)
+        occupied = self.state.get("occupied")
+        # temporary, until the harness's state_unchanged fault hands back a
+        # copy of its donated input (PERF.md §7): a state lost to the step
+        # just dispatched is made anew next tick, so its mask is not marked
+        if occupied is not None and not occupied.is_deleted():
+            self.state["occupied"] = occupied.at[b].set(flag)
 
     def expert_load(self) -> Optional[np.ndarray]:
         """Routed (token, expert) choices of the occupied slots since the
@@ -178,68 +220,116 @@ class ServingEngine:
 
     # -- engine tick ---------------------------------------------------------
     def tick(self):
-        """One step of the slot grid. Its spans, in order, cover it:
-        admit (with each slot reset), dispatch, sample, bookkeep."""
+        """One step of the slot grid, one step in flight: admit; build the
+        feed for the slots the next step runs; dispatch the sampling of the
+        step in flight and then the next step; read the samples of the step
+        in flight (the tick's one host sync, while the next step runs);
+        take them into their requests. Its spans, in order, cover it: admit
+        (with each slot reset), dispatch, sample, bookkeep; a tick with no
+        step in flight reads nothing, one with no slot to feed dispatches
+        only the sampling. An EOS is read one step late: the token its
+        slot made in the step after it is discarded
+        (``discarded_slot_steps``).
+        → False when there was nothing to do."""
         span = telemetry.span
         with span(self._sp_tick):
             with span(self._sp_admit):
                 if self._state_lost():
                     self.state = self._fresh_state()
                 self._admit()
-                if all(s is None for s in self.slots):
+                feeding = any(s is not None for s in self.slots)
+                if not feeding and not self.in_flight:
                     return False
             with span(self._sp_dispatch):
-                logits, self.state = self._step(
-                    self.params, self.state,
-                    jax.device_put(self.current_token, self.device))
-            with span(self._sp_sample):
-                if self.greedy:
-                    nxt = np.asarray(jnp.argmax(logits[:, -1], -1), np.int32)
-                else:
-                    self.key, k = jax.random.split(self.key)
-                    nxt = np.asarray(
-                        jax.random.categorical(k, logits[:, -1]), np.int32)
-                self.host_syncs += 1
-            with span(self._sp_bookkeep):
-                self.ticks += 1
-                self._bookkeep(nxt)
+                drawn = self._dispatch(feeding)
+            if drawn is not None:
+                with span(self._sp_sample):
+                    drawn = np.asarray(drawn)
+                    self.host_syncs += 1
+                with span(self._sp_bookkeep):
+                    self._bookkeep(drawn)
         return True
 
-    def _bookkeep(self, nxt: np.ndarray):
-        """Advance every occupied slot by the tick's sampled tokens: feed
-        the next prompt token, or take the sample and retire the request
-        when it is done."""
+    def _feed(self):
+        """The host's tokens for the next step, a fresh array each tick
+        (a step in flight may still read the last one): each occupied
+        slot's next prompt token, or −1 where it takes its own sample.
+        Advances the prompt cursors. → (feed, (slot, request, last) for
+        each token the step makes)."""
+        feed = np.zeros((self.B, 1), np.int32)
+        makes = []
         for b, req in enumerate(self.slots):
             if req is None:
                 continue
-            cur = int(self.prompt_cursor[b])
-            if cur < len(req.prompt):              # still feeding the prompt
-                self.current_token[b, 0] = req.prompt[cur]
-                self.prompt_cursor[b] = cur + 1
+            cur, n = int(self.prompt_cursor[b]), len(req.prompt)
+            feed[b, 0] = req.prompt[cur] if cur < n else -1
+            self.prompt_cursor[b] = cur = min(cur + 1, n)
+            if cur < n:
                 self.prompt_slot_ticks += 1
                 continue
+            # the step makes token number pos + 2 - n, at position pos + 1
+            pos = self.position(b)
+            last = pos + 2 - n >= req.max_new or pos + 1 >= self.max_seq - 1
+            makes.append((b, req, last))
             self.decode_slot_ticks += 1
-            tok = int(nxt[b])
+        return feed, makes
+
+    def _dispatch(self, feeding: bool):
+        """Dispatch the sampling of the step in flight, if any, then, when
+        a slot is fed, the next step; free the slots whose last token that
+        step makes. → the samples of the step that was in flight, on the
+        device, or None."""
+        feed, makes = self._feed()
+        tokens, drawn = jax.device_put(feed, self.device), None
+        if self.in_flight:
+            tokens, drawn, self.key = self._sample(self._logits, tokens,
+                                                   self.key)
+            self._logits = None
+        if not feeding:
+            return drawn
+        self.overlapped_ticks += drawn is not None
+        self._logits, self.state = self._step(self.params, self.state,
+                                              tokens)
+        self.ticks += 1
+        self._unread.append(makes)
+        for b, req in enumerate(self.slots):
+            if req is not None:
+                self._pos[b] += 1
+        for b, _, last in makes:
+            if last:
+                self._release(b)
+        return drawn
+
+    def _bookkeep(self, drawn: np.ndarray):
+        """Take the oldest unread step's samples into the requests it made
+        tokens for; retire a request at its last token or an EOS."""
+        now = time.perf_counter()
+        for b, req, last in self._unread[0]:
+            if req.done:                      # retired by an EOS a step ago
+                self.discarded_slot_steps += 1
+                continue
+            tok = int(drawn[b, 0])
             if not req.generated:
-                req.first_token_at = time.perf_counter()
+                req.first_token_at = now
             req.generated.append(tok)
-            self.current_token[b, 0] = tok
-            if (len(req.generated) >= req.max_new
-                    or (req.eos_id is not None and tok == req.eos_id)
-                    or self.position(b) >= self.max_seq - 1):
-                self._retire(b)
+            if last or (req.eos_id is not None and tok == req.eos_id):
+                self._retire(req)
+        del self._unread[0]
 
     def position(self, b: int) -> int:
         """Slot ``b``'s position in ``state["pos"]``, known on the host:
         admission zeroes it and every step adds one, so it counts the
-        tokens the slot has fed, which are all but the one waiting in
-        ``current_token`` (the prompt's next, or the newest sample)."""
-        req = self.slots[b]
-        return int(self.prompt_cursor[b]) + len(req.generated) - 1
+        tokens the dispatched steps have fed the slot."""
+        return int(self._pos[b])
+
+    @property
+    def in_flight(self) -> bool:
+        """A step is dispatched whose tokens the host has not read."""
+        return self._logits is not None
 
     def run_until_drained(self, max_ticks: int = 10_000):
-        while (self.queue or any(s is not None for s in self.slots)) \
-                and self.ticks < max_ticks:
+        while (self.queue or any(s is not None for s in self.slots)
+               or self.in_flight) and self.ticks < max_ticks:
             self.tick()
         return self.completed
 
@@ -247,12 +337,19 @@ class ServingEngine:
         """Crash recovery: drop all in-flight work and return to an empty
         slot grid (caches/positions are re-zeroed per slot on admit; a
         state lost to a failed donated step is made anew).
-        → the requests that were lost (queued + slotted)."""
-        lost = [r for r in self.slots if r is not None] + list(self.queue)
+        → the requests that were lost (queued, slotted, and those waiting
+        for their last token)."""
+        lost = {}
+        for r in itertools.chain(
+                self.slots, (r for makes in self._unread for _, r, _ in makes),
+                self.queue):
+            if r is not None and not r.done:
+                lost.setdefault(id(r), r)
         self.slots = [None] * self.B
         self.queue = []
-        self.current_token[:] = 0
+        self._logits, self._unread = None, []
         self.prompt_cursor[:] = 0
+        self._pos[:] = 0
         if self._state_lost():
             self.state = self._fresh_state()
         self.state["pos"] = jax.device_put(
@@ -260,7 +357,7 @@ class ServingEngine:
         if "occupied" in self.state:
             self.state["occupied"] = jax.device_put(
                 np.zeros((self.B,), np.int32), self.device)
-        return lost
+        return list(lost.values())
 
 
 # ---------------------------------------------------------------------------

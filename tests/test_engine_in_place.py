@@ -147,19 +147,20 @@ def _requests(vocab):
 
 def _serve(eng, vocab):
     """Serve the requests, two joining every third tick → (tokens a
-    request, tick each retired on, host syncs each tick)."""
+    request, tick each retired on, (host syncs, a step in flight at its
+    start) each tick)."""
     reqs = _requests(vocab)
     pending, retired_at, syncs = list(reqs), {}, []
     for t in range(1000):
-        if not (pending or eng.queue or any(eng.slots)):
+        if not (pending or eng.queue or any(eng.slots) or eng.in_flight):
             break
         if t % 3 == 0:
             for r in pending[:2]:
                 eng.submit(r)
             del pending[:2]
-        before = eng.host_syncs
+        before, in_flight = eng.host_syncs, eng.in_flight
         if eng.tick():
-            syncs.append(eng.host_syncs - before)
+            syncs.append((eng.host_syncs - before, in_flight))
         for r in eng.completed:
             retired_at.setdefault(r.rid, eng.ticks)
     return {r.rid: r.generated for r in reqs}, retired_at, syncs
@@ -176,4 +177,5 @@ def test_in_place_serves_the_plain_walks_tokens(stacked_model, greedy,
     assert got[0] == want[0]
     assert got[1] == want[1] and len(got[1]) == len(_requests(2))
     assert len(got[2]) >= 40
-    assert got[2] == [1] * len(got[2])         # one host sync a tick
+    # one host sync a tick, of the step in flight
+    assert got[2] == want[2] and all(n == f for n, f in got[2])

@@ -1,7 +1,7 @@
 """The engine tick takes each slot's position from the host: the position
-it uses is the one on the device, retirements at ``max_seq`` fall on the
-same tick as under a device read, and the served tokens do not change.
-Tiny dense and SSM models on the CPU."""
+it uses is the one on the device, retirements at ``max_seq`` follow the
+device's position, and the served tokens do not change. Tiny dense and
+SSM models on the CPU."""
 import jax
 import pytest
 
@@ -49,6 +49,10 @@ def _occupied(eng):
     return [b for b, r in enumerate(eng.slots) if r is not None]
 
 
+def _busy(eng):
+    return eng.queue or _occupied(eng) or eng.in_flight
+
+
 def test_host_position_is_the_devices(model):
     eng = _engine(model)
     reqs = _requests()
@@ -67,7 +71,7 @@ def test_host_position_is_the_devices(model):
     assert [int(p) for p in eng.state["pos"]] == [0] * eng.B
     for r in reqs[5:]:
         eng.submit(r)
-    while eng.queue or _occupied(eng):
+    while _busy(eng):
         assert eng.tick()
         for b in _occupied(eng):
             assert eng.position(b) == int(eng.state["pos"][b])
@@ -75,30 +79,47 @@ def test_host_position_is_the_devices(model):
 
 
 def test_max_seq_retires_on_the_device_reads_tick(model):
+    """A request whose last token the dispatched step makes, by max_new
+    or because the device's position after it reaches max_seq - 1, leaves
+    its slot right after that step and retires on the next tick, when its
+    token reaches the host."""
     eng = _engine(model)
     reqs = _requests()
     for r in reqs:
         eng.submit(r)
-    hit_max_seq = 0
-    while eng.queue or _occupied(eng):
-        generating = {b: r for b, r in enumerate(eng.slots) if r is not None
-                      and eng.prompt_cursor[b] >= len(r.prompt)}
-        queued = {id(r) for r in eng.queue}
-        assert eng.tick()
-        for r in eng.slots + eng.completed:
-            # admitted this tick with a one-token prompt: generated at once
-            if r is not None and id(r) in queued and len(r.prompt) == 1:
-                generating[r.slot] = r
-        for b, r in generating.items():
-            pos = int(eng.state["pos"][b])   # the old rule's device read
+    step, made, last_on, n = eng._step, {}, {}, [0]
+
+    def spy(p, s, t):
+        making = [(b, r) for b, r in enumerate(eng.slots) if r is not None
+                  and eng.prompt_cursor[b] >= len(r.prompt)]
+        logits, s = step(p, s, t)
+        for b, r in making:
+            made[r.rid] = made.get(r.rid, 0) + 1
+            pos = int(s["pos"][b])           # the device read, after it
             by_max_seq = pos >= MAX_SEQ - 1
-            old_rule = (len(r.generated) >= r.max_new
-                        or (r.eos_id is not None and r.generated[-1] == r.eos_id)
-                        or by_max_seq)
-            assert r.done == old_rule, (r.rid, pos)
-            hit_max_seq += r.done and by_max_seq \
-                and len(r.generated) < r.max_new
-    assert hit_max_seq == 2
+            if made[r.rid] >= r.max_new or by_max_seq:
+                last_on.setdefault(r.rid, (n[0], by_max_seq
+                                           and made[r.rid] < r.max_new))
+        return logits, s
+
+    eng._step = spy
+    retired_on = {}
+    while _busy(eng):
+        n[0] += 1
+        assert eng.tick()
+        for r in eng.completed:
+            retired_on.setdefault(r.rid, n[0])
+        for rid, (t, _) in last_on.items():
+            r = reqs[rid]
+            if t == n[0] and not r.done:     # slot freed, token unread
+                assert eng.slots[r.slot] is not r
+    for r in reqs:
+        if r.eos_id is not None and r.generated[-1] == r.eos_id \
+                and len(r.generated) < made[r.rid]:
+            continue                         # ended by its EOS
+        assert retired_on[r.rid] == last_on[r.rid][0] + 1, r.rid
+        assert len(r.generated) == made[r.rid]
+    assert sum(by for _, by in last_on.values()) == 2
     long = [r for r in reqs if len(r.prompt) + r.max_new > MAX_SEQ]
     assert [len(r.generated) for r in long] == \
         [MAX_SEQ - len(r.prompt) for r in long]
@@ -113,7 +134,7 @@ def test_served_tokens_match_the_device_read_path(model, greedy):
         for r in reqs:
             eng.submit(r)
         retired_at, syncs = {}, []
-        while eng.queue or _occupied(eng):
+        while _busy(eng):
             before = eng.host_syncs
             assert eng.tick()
             syncs.append(eng.host_syncs - before)
@@ -121,5 +142,6 @@ def test_served_tokens_match_the_device_read_path(model, greedy):
                 retired_at.setdefault(r.rid, eng.ticks)
         runs.append(({r.rid: r.generated for r in reqs}, retired_at))
         if cls is ServingEngine:
-            assert syncs == [1] * eng.ticks     # the sampled tokens alone
+            # the sampled tokens alone, read from the tick after the first
+            assert syncs == [0] + [1] * eng.ticks
     assert runs[0] == runs[1]

@@ -114,11 +114,16 @@ def test_each_tick_holds_its_phases_in_order(model, recorder):
     _submit_mixed(eng)
     eng.run_until_drained()
     ticks = recorder.tree()
-    assert len(ticks) == eng.ticks
+    # one tick more than steps: the last dispatches only the sampling of
+    # the last step, and reads its tokens
+    assert len(ticks) == eng.ticks + 1
     resets = 0
-    for name, children in ticks:
+    for i, (name, children) in enumerate(ticks):
         assert name == f"engine.tick:{dev}"
-        assert [c[0] for c in children] == [f"{p}:{dev}" for p in PHASES]
+        phases = PHASES
+        if i == 0:                  # no step in flight: nothing to read
+            phases = PHASES[:2]
+        assert [c[0] for c in children] == [f"{p}:{dev}" for p in phases]
         admit = children[0][1]
         assert all(c == [f"engine.slot_reset:{dev}", []] for c in admit)
         resets += len(admit)
@@ -140,27 +145,33 @@ def test_counters_and_stamps_follow_the_slots(model):
         occupied.append(sum(r is not None for r in eng.slots))
         return step(p, s, t)
     eng._step = counting_step
-    first_tick, admit_tick = {}, {}
-    while eng.queue or any(s is not None for s in eng.slots):
-        syncs = eng.host_syncs
+    first_tick, admit_tick, n = {}, {}, 0
+    while eng.queue or any(s is not None for s in eng.slots) \
+            or eng.in_flight:
+        syncs, in_flight = eng.host_syncs, eng.in_flight
         queued = {r.rid for r in eng.queue}
         assert eng.tick()
+        n += 1
         for r in reqs:
             if r.rid in queued and r.slot >= 0:
-                admit_tick[r.rid] = eng.ticks
+                admit_tick[r.rid] = n
             if r.generated and r.rid not in first_tick:
-                first_tick[r.rid] = eng.ticks
-        # the token copy alone: positions are known on the host
-        assert eng.host_syncs - syncs == 1
+                first_tick[r.rid] = n
+        # the token copy alone, of the step in flight: positions are known
+        # on the host
+        assert eng.host_syncs - syncs == in_flight
     for r in reqs:
-        # the len(prompt)-th tick, counting the one that admitted it
-        assert first_tick[r.rid] - admit_tick[r.rid] + 1 == len(r.prompt)
+        # the len(prompt)-th tick makes it, counting the one that admitted
+        # it; the next reads it
+        assert first_tick[r.rid] - admit_tick[r.rid] == len(r.prompt)
         assert (r.submitted_at <= r.admitted_at <= r.first_token_at
                 <= r.finished_at)
     assert eng.prompt_slot_ticks == sum(len(r.prompt) - 1 for r in reqs)
     assert eng.decode_slot_ticks == sum(len(r.generated) for r in reqs)
     assert eng.prompt_slot_ticks + eng.decode_slot_ticks == sum(occupied)
     assert eng.ticks == len(occupied)
+    assert eng.overlapped_ticks == eng.ticks - 1 == eng.host_syncs - 1
+    assert eng.discarded_slot_steps == 0
 
 
 def test_step_module_has_a_stable_name(model):
